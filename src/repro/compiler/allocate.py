@@ -33,10 +33,8 @@ class ScratchPlan:
     naive_bytes: int                 # sum of full intermediate sizes
     itemsize: int = 4
     # streamed buffer -> its (2, row_block, minor) ping-pong pair via the
-    # shared schedule.ping_pong_shape — the VMEM scratch sizing the chain
-    # megakernel's handoff uses (repro.kernels.tm_affine.chain allocates the
-    # pair on the chain output's plan; both sides bound one slot by the same
-    # two-segment budget), so slot accounting and kernel scratch agree
+    # shared schedule.ping_pong_shape: a streamed slot is charged two
+    # segments of the same budget the kernels' grids are cut by
     kernel_scratch_shapes: dict[str, tuple[int, int, int]] = \
         dataclasses.field(default_factory=dict)
 

@@ -191,7 +191,6 @@ class CompiledTMProgram:
 
     def run_phase(self, phase: Phase, env: dict[str, Any], *,
                   backend: str = "fused",
-                  interpret: bool = True,
                   fuse_chains: bool = False,
                   exact: bool = False,
                   tracer=None,
@@ -233,13 +232,11 @@ class CompiledTMProgram:
         tracer = NULL_TRACER if tracer is None else tracer
         if not tracer.enabled:
             return self._exec_phase(phase, env, backend=backend,
-                                    interpret=interpret,
                                     fuse_chains=fuse_chains, exact=exact,
                                     quarantine=quarantine)
         with tracer.span(f"phase/{phase.index}/{phase.kind}",
                          backend=backend) as sp:
             rep = self._exec_phase(phase, env, backend=backend,
-                                   interpret=interpret,
                                    fuse_chains=fuse_chains, exact=exact,
                                    tracer=tracer, quarantine=quarantine)
             if tracer.detail == "instr":
@@ -259,13 +256,12 @@ class CompiledTMProgram:
         return rep
 
     def _exec_phase(self, phase: Phase, env: dict[str, Any], *,
-                    backend: str, interpret: bool, fuse_chains: bool,
+                    backend: str, fuse_chains: bool,
                     exact: bool, tracer=NULL_TRACER,
                     quarantine: set | None = None,
                     ) -> LoweringReport | TPUPhaseReport:
         if phase.kind == "fused":
             return self._exec_fused(phase, env, backend=backend,
-                                    interpret=interpret,
                                     fuse_chains=fuse_chains, exact=exact,
                                     tracer=tracer, quarantine=quarantine)
         if phase.kind == "tpu":
@@ -306,16 +302,16 @@ class CompiledTMProgram:
             return TPUPhaseReport(
                 phase_index=phase.index, n_eqns=len(phase.node_indices),
                 jitted=False, xla_computations=len(phase.node_indices))
-        ex = TMExecutor(backend=backend, interpret=interpret,
-                        params=self.params, fuse_chains=fuse_chains,
-                        tracer=tracer, quarantine=quarantine)
+        ex = TMExecutor(backend=backend, params=self.params,
+                        fuse_chains=fuse_chains, tracer=tracer,
+                        quarantine=quarantine)
         bufs = {n: env[n] for n in phase.program.inputs}
         out, lowering, _ = ex.run(phase.program, bufs)
         env.update(out)
         return lowering
 
     def _exec_fused(self, phase: Phase, env: dict[str, Any], *,
-                    backend: str, interpret: bool, fuse_chains: bool,
+                    backend: str, fuse_chains: bool,
                     exact: bool, tracer=NULL_TRACER,
                     quarantine: set | None = None) -> LoweringReport:
         """Execute a cross-engine fused phase: the compute eqn + its TM run
@@ -341,8 +337,9 @@ class CompiledTMProgram:
             sb = self.params.segment_bytes if self.params is not None \
                 else None
             lowered = lower_xengine(direction, node, eqn_srcs, instrs,
-                                    tm_srcs, interpret, segment_bytes=sb,
-                                    quarantine=quarantine)
+                                    tm_srcs, segment_bytes=sb,
+                                    quarantine=quarantine,
+                                    declines=report.declines)
             if lowered is not None:
                 val, rec = lowered
                 env[rec.dst] = val
@@ -358,16 +355,18 @@ class CompiledTMProgram:
             report.records.append(Lowering(
                 dst=node.dst_names[0], opcode="tpu",
                 path=f"xla.{node.primitive_name}",
-                reason="cross-engine lowering declined: split path"))
+                reason="; ".join(["cross-engine lowering declined: split "
+                                  "path"] + report.declines)))
 
         def run_tm():
-            ex = TMExecutor(backend=backend, interpret=interpret,
-                            params=self.params, fuse_chains=fuse_chains,
-                            tracer=tracer, quarantine=quarantine)
+            ex = TMExecutor(backend=backend, params=self.params,
+                            fuse_chains=fuse_chains, tracer=tracer,
+                            quarantine=quarantine)
             bufs = {n: env[n] for n in phase.program.inputs}
             out, lowering, _ = ex.run(phase.program, bufs)
             env.update(out)
             report.records.extend(lowering.records)
+            report.declines.extend(lowering.declines)
 
         if direction == "compute_to_tm":
             run_eqn()
@@ -382,7 +381,7 @@ class CompiledTMProgram:
         return jax.tree_util.tree_unflatten(self.out_tree, outs)
 
     def run_async(self, env: dict[str, Any], *, runtime,
-                  backend: str = "fused", interpret: bool = True,
+                  backend: str = "fused",
                   fuse_chains: bool = False, exact: bool = False,
                   label: str = "", tracer=None,
                   quarantine: set | None = None):
@@ -403,7 +402,6 @@ class CompiledTMProgram:
         for phase in self.partition_report.phases:
             def task(ph=phase):
                 rep = self.run_phase(ph, env, backend=backend,
-                                     interpret=interpret,
                                      fuse_chains=fuse_chains, exact=exact,
                                      tracer=tracer, quarantine=quarantine)
                 return [env[n] for n in ph.writes], rep
@@ -412,7 +410,7 @@ class CompiledTMProgram:
                 label=f"{label}phase{phase.index}:{phase.kind}"))
         return events
 
-    def run(self, *args, backend: str = "fused", interpret: bool = True,
+    def run(self, *args, backend: str = "fused",
             fuse_chains: bool = False, exact: bool = False, runtime=None,
             tracer=None, quarantine: set | None = None,
             ) -> tuple[Any, list[LoweringReport]]:
@@ -429,7 +427,6 @@ class CompiledTMProgram:
         reports: list[LoweringReport | TPUPhaseReport] = []
         if runtime is not None:
             events = self.run_async(env, runtime=runtime, backend=backend,
-                                    interpret=interpret,
                                     fuse_chains=fuse_chains, exact=exact,
                                     tracer=tracer, quarantine=quarantine)
             for ev in events:   # sink sync: deps complete transitively
@@ -437,7 +434,6 @@ class CompiledTMProgram:
         else:
             for phase in self.partition_report.phases:
                 reports.append(self.run_phase(phase, env, backend=backend,
-                                              interpret=interpret,
                                               fuse_chains=fuse_chains,
                                               exact=exact, tracer=tracer,
                                               quarantine=quarantine))
@@ -445,9 +441,9 @@ class CompiledTMProgram:
         return self.outputs_from(env), lowerings
 
     def __call__(self, *args, backend: str = "fused",
-                 interpret: bool = True, fuse_chains: bool = False,
+                 fuse_chains: bool = False,
                  exact: bool = False, runtime=None, tracer=None):
-        out, lowerings = self.run(*args, backend=backend, interpret=interpret,
+        out, lowerings = self.run(*args, backend=backend,
                                   fuse_chains=fuse_chains, exact=exact,
                                   runtime=runtime, tracer=tracer)
         self.last_lowering = lowerings

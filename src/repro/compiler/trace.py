@@ -15,8 +15,8 @@ equations.  Two match sources:
   ``tm_evaluate`` primitives whose params carry the exact map, so the match
   is trivial and lossless.
 
-``pjit`` sub-jaxprs are inlined when (and only when) they contain matchable
-equations — ``jnp.pad``/``jnp.flip`` wrap their primitives in pjit — so the
+``jit`` sub-jaxprs are inlined when (and only when) they contain matchable
+equations — ``jnp.pad``/``jnp.flip`` wrap their primitives in jit — so the
 matcher sees through jnp's convenience wrappers without exploding opaque
 compute into per-eqn nodes.
 """
@@ -43,7 +43,7 @@ _CONST_FOLD_LIMIT = 1 << 20
 _EW_PRIMS = {"add": EwOp.ADD, "sub": EwOp.SUB, "mul": EwOp.MUL,
              "max": EwOp.MAX}
 
-# primitives the matcher may claim (used for the pjit-inlining decision)
+# primitives the matcher may claim (used for the jit-inlining decision)
 _TM_PRIM_NAMES = frozenset({
     "transpose", "reshape", "squeeze", "slice", "dynamic_slice",
     "dynamic_update_slice", "gather", "pad",
@@ -65,9 +65,9 @@ def _aval_shape(v) -> tuple[int, ...]:
 def _is_matchable(eqn, strict: bool = False) -> bool:
     """Cheap shape-level predicate: could :func:`_match_tm` claim this eqn?
 
-    ``strict`` is the pjit-inlining mode: a ``dynamic_slice`` counts only
+    ``strict`` is the jit-inlining mode: a ``dynamic_slice`` counts only
     when its starts are Literals, because a traced start can never match —
-    inlining a pjit on its account would explode one opaque XLA call into
+    inlining a jit on its account would explode one opaque XLA call into
     per-eqn TPU nodes for nothing.  (At top level the gate stays permissive:
     ``_match_tm``'s ``get_const`` also resolves const-folded starts.)"""
     name = eqn.primitive.name
@@ -89,7 +89,7 @@ def _contains_tm(jaxpr) -> bool:
     for eqn in jaxpr.eqns:
         if _is_matchable(eqn, strict=True):
             return True
-        if eqn.primitive.name == "pjit" and _contains_tm(eqn.params["jaxpr"].jaxpr):
+        if eqn.primitive.name == "jit" and _contains_tm(eqn.params["jaxpr"].jaxpr):
             return True
     return False
 
@@ -341,7 +341,7 @@ def _walk(builder: _Builder, jaxpr, consts, env) -> None:
         env[cv] = builder.const_buffer(cval)
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
-        if name == "pjit" and _contains_tm(eqn.params["jaxpr"].jaxpr):
+        if name == "jit" and _contains_tm(eqn.params["jaxpr"].jaxpr):
             inner = eqn.params["jaxpr"]
             sub_env = {}
             for iv, ov in zip(inner.jaxpr.invars, eqn.invars):
@@ -361,7 +361,7 @@ def _walk(builder: _Builder, jaxpr, consts, env) -> None:
 
         # trace-time constant folding wins over matching: an all-constant
         # eqn becomes a register constant downstream matchers can *read*
-        # (e.g. the index-preprocessing chain inside jnp.take's pjit must
+        # (e.g. the index-preprocessing chain inside jnp.take's jit must
         # fold so the gather matcher sees a constant index vector) — a
         # matched TM node would hide the value behind a buffer name
         foldable = (all(isinstance(v, Literal) or env[v] in builder.consts

@@ -19,14 +19,24 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.instr import TMInstr
+from repro.platform import pallas_interpret
 
 # repro.ft.FaultInjector.install() points this at its fire() method; None in
 # production.  It fires INSIDE the rule-execution try below, so an injected
 # lowering fault exercises the quarantine/fallback ladder, not a crash.
 fault_hook: Callable[[str, str], None] | None = None
+
+
+class Decline(str):
+    """What a rule returns instead of a path (``matches``) or a result
+    (chain/cross-engine ``lower``) when it recognizes the work but will not
+    launch it on this platform — the string says why, and the caller's
+    fallback record carries it.  Deciding up front keeps a compiler refusal
+    from ever reaching a launch."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +81,9 @@ class LoweringReport:
 
     backend: str
     records: list[Lowering] = dataclasses.field(default_factory=list)
+    # "rule: why" for chain rules that declined up front (the chain's links
+    # then lower one by one, each with its own record)
+    declines: list[str] = dataclasses.field(default_factory=list)
 
     def paths(self) -> list[str]:
         return [r.path for r in self.records]
@@ -111,7 +124,9 @@ class KernelRule:
     ``matches(ins, srcs, batch_dims, segment_bytes=None)`` returns the
     lowering path string when the rule can execute the instruction (None
     otherwise); ``run(ins, srcs, batch_dims, interpret, segment_bytes=None)``
-    executes it.  ``segment_bytes`` is the ping-pong buffer budget
+    executes it, with ``interpret`` decided by
+    :func:`repro.platform.pallas_interpret` from the operands.
+    ``segment_bytes`` is the ping-pong buffer budget
     (:class:`~repro.core.schedule.CycleParams.segment_bytes`); None means the
     default — rules whose grids honour the budget re-segment from it, the
     rest accept and ignore it.  ``priority`` orders rules (higher first) so
@@ -229,10 +244,22 @@ def quarantine_key(rule_name: str, opcode: str,
     return (rule_name, opcode, shapes)
 
 
+def _arrays(*groups):
+    """The array operands among nested source lists (None slots, literals
+    and scalars dropped) — what the interpret decision looks at."""
+    for g in groups:
+        for s in g:
+            if isinstance(s, (list, tuple)):
+                yield from _arrays(s)
+            elif isinstance(s, jax.Array):
+                yield s
+
+
 def lower_instr(ins: TMInstr, srcs: Sequence[jnp.ndarray], batch_dims: int,
-                interpret: bool, segment_bytes: int | None = None,
+                segment_bytes: int | None = None,
                 quarantine: set | None = None,
                 faults: list | None = None,
+                declines: list | None = None,
                 ) -> tuple[jnp.ndarray, Lowering] | None:
     """Lower one instruction through the registry.
 
@@ -251,13 +278,21 @@ def lower_instr(ins: TMInstr, srcs: Sequence[jnp.ndarray], batch_dims: int,
     a raising rule propagates, preserving fail-fast semantics for direct
     executor use.  ``faults`` (optional caller-owned list) collects one
     ``(rule name, why)`` row per skipped rule, so a None return can still
-    tell the caller its engine fallback is a degradation.
+    tell the caller its engine fallback is a degradation.  ``declines``
+    (optional caller-owned list) collects ``(rule name, why)`` for rules
+    that returned a :class:`Decline` — a platform limit known before any
+    launch, not a fault.
     """
     _ensure_registered()
+    interpret = pallas_interpret(*_arrays(srcs))
     degraded = False
     for rule in _RULES:
         path = rule.matches(ins, srcs, batch_dims, segment_bytes=segment_bytes)
         if path is None:
+            continue
+        if isinstance(path, Decline):
+            if declines is not None:
+                declines.append((rule.name, str(path)))
             continue
         if quarantine is not None:
             qkey = quarantine_key(rule.name, ins.opcode.value, srcs)
@@ -296,9 +331,10 @@ def lower_instr(ins: TMInstr, srcs: Sequence[jnp.ndarray], batch_dims: int,
 
 def lower_chain(instrs: Sequence[TMInstr],
                 srcs: Sequence[Sequence[jnp.ndarray | None]],
-                batch_dims: int, interpret: bool,
+                batch_dims: int,
                 segment_bytes: int | None = None,
                 quarantine: set | None = None,
+                declines: list | None = None,
                 ) -> tuple[jnp.ndarray, Lowering] | None:
     """Lower a whole forwarding chain through the chain registry.
 
@@ -314,9 +350,11 @@ def lower_chain(instrs: Sequence[TMInstr],
     With a ``quarantine`` set, a quarantined or raising chain rule is
     skipped the same way as in :func:`lower_instr` — the chain then
     executes link-by-link, each link taking its own (quarantine-aware)
-    instruction lowering.
+    instruction lowering.  A rule returning a :class:`Decline` adds
+    ``"rule: why"`` to ``declines``.
     """
     _ensure_registered()
+    interpret = pallas_interpret(*_arrays(srcs))
     for rule in _CHAIN_RULES:
         if quarantine is not None:
             qkey = quarantine_key(rule.name, "chain", srcs[0])
@@ -330,6 +368,10 @@ def lower_chain(instrs: Sequence[TMInstr],
                 raise
             quarantine.add(quarantine_key(rule.name, "chain", srcs[0]))
             continue
+        if isinstance(lowered, Decline):
+            if declines is not None:
+                declines.append(f"{rule.name}: {lowered}")
+            continue
         if lowered is not None:
             val, path, seg = lowered
             return val, Lowering(dst=instrs[-1].dst, opcode="chain",
@@ -341,8 +383,9 @@ def lower_chain(instrs: Sequence[TMInstr],
 def lower_xengine(direction: str, eqn_node, eqn_srcs: Sequence,
                   instrs: Sequence[TMInstr],
                   tm_srcs: Sequence[Sequence[jnp.ndarray | None]],
-                  interpret: bool, segment_bytes: int | None = None,
+                  segment_bytes: int | None = None,
                   quarantine: set | None = None,
+                  declines: list | None = None,
                   ) -> tuple[jnp.ndarray, Lowering] | None:
     """Lower a cross-engine crossing (compute eqn + adjacent TM chain)
     through the cross-engine registry.
@@ -356,8 +399,10 @@ def lower_xengine(direction: str, eqn_node, eqn_srcs: Sequence,
     Returns None when no rule claims the crossing — the caller then
     executes eqn and chain separately, bit-exact.  ``quarantine`` works as
     in :func:`lower_instr`: a raising rule is quarantined under its
-    shape-class key and skipped on later runs."""
+    shape-class key and skipped on later runs; a :class:`Decline` adds
+    ``"rule: why"`` to ``declines``."""
     _ensure_registered()
+    interpret = pallas_interpret(*_arrays(eqn_srcs, tm_srcs))
     dst = (instrs[-1].dst if direction == "compute_to_tm"
            else eqn_node.dst_names[0])
     for rule in _XENGINE_RULES:
@@ -377,6 +422,10 @@ def lower_xengine(direction: str, eqn_node, eqn_srcs: Sequence,
                 raise
             quarantine.add(quarantine_key(rule.name, f"xchain.{direction}",
                                           eqn_srcs))
+            continue
+        if isinstance(lowered, Decline):
+            if declines is not None:
+                declines.append(f"{rule.name}: {lowered}")
             continue
         if lowered is not None:
             val, path, seg = lowered

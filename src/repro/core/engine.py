@@ -20,6 +20,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.affine import MixedRadixMap
 from repro.core.spec import row_major_strides
@@ -40,18 +41,22 @@ def _row_int_form(row, off) -> tuple[tuple[int, ...], int, int]:
     return nums, int(off * L), L
 
 
-def gather_indices(m: MixedRadixMap) -> tuple[jnp.ndarray, jnp.ndarray]:
+def gather_indices(m: MixedRadixMap, xp=jnp) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Flat input index + validity mask for every output element.
 
     Returns ``(flat_idx, valid)`` of shape ``m.out_shape`` (int32 / bool).
     Traced with concrete shapes — everything here folds to constants under
     jit; on TPU the index tensors are computed on-device from iota (no host
-    transfer), exactly like the TMU's runtime address generator.
+    transfer), exactly like the TMU's runtime address generator.  With
+    ``xp=numpy`` the same arithmetic runs on the host (kernel address
+    tables are built that way, without a device dispatch per operation).
     """
     nd_out = len(m.out_shape)
-    coords = [
-        jax.lax.broadcasted_iota(jnp.int32, m.out_shape, d) for d in range(nd_out)
-    ]
+    if xp is jnp:
+        coords = [jax.lax.broadcasted_iota(jnp.int32, m.out_shape, d)
+                  for d in range(nd_out)]
+    else:  # broadcastable per-axis coordinates, expanded by the arithmetic
+        coords = list(np.indices(m.out_shape, dtype=np.int32, sparse=True))
     # mixed-radix digit expansion (quotient in place, remainders appended)
     digits = list(coords)
     for sp in m.splits:
@@ -61,23 +66,23 @@ def gather_indices(m: MixedRadixMap) -> tuple[jnp.ndarray, jnp.ndarray]:
         digits.append(r)
     # affine rows -> input coordinates (exact floor with common denominator)
     in_coords = []
-    valid = jnp.ones(m.out_shape, dtype=bool)
+    valid = xp.ones(m.out_shape, dtype=bool)
     for row, off in zip(m.affine.A, m.affine.b):
         nums, offn, L = _row_int_form(row, off)
-        acc = jnp.full(m.out_shape, offn, dtype=jnp.int32)
+        acc = xp.full(m.out_shape, offn, dtype=xp.int32)
         for n, d in zip(nums, digits):
             if n != 0:
                 acc = acc + n * d
-        c = acc if L == 1 else jnp.floor_divide(acc, L)
+        c = acc if L == 1 else xp.floor_divide(acc, L)
         in_coords.append(c)
     for c, s in zip(in_coords, m.in_shape):
         valid = valid & (c >= 0) & (c < s)
     for d, bound in m.digit_bounds:
         valid = valid & (digits[d] < bound)
     strides = row_major_strides(m.in_shape)
-    flat = jnp.zeros(m.out_shape, dtype=jnp.int32)
+    flat = xp.zeros(m.out_shape, dtype=xp.int32)
     for c, s, st in zip(in_coords, m.in_shape, strides):
-        flat = flat + jnp.clip(c, 0, s - 1) * st
+        flat = flat + xp.clip(c, 0, s - 1) * st
     return flat, valid
 
 

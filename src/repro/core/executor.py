@@ -19,7 +19,8 @@ Backends:
     intermediates never materialize), then execute.
   * ``pallas``    — lower each instruction through the kernel-dispatch
     registry (:mod:`repro.core.dispatch`) onto the hand-written Pallas
-    kernels; unsupported configurations fall back to the reference engine.
+    kernels (compiled on a TPU, interpreted elsewhere); unsupported
+    configurations fall back to the reference engine.
     ``last_lowering`` records which path each instruction took.
 
 The reference/fused executors are jit-compatible: running them under
@@ -51,7 +52,6 @@ BACKENDS = ("reference", "fused", "pallas")
 @dataclasses.dataclass
 class TMExecutor:
     backend: str = "fused"  # "reference" | "fused" | "pallas"
-    interpret: bool = True  # Pallas interpreter mode (CPU-safe); False on TPU
     # custom cycle params re-segment the launched Pallas grids (the ping-pong
     # budget params.segment_bytes flows executor -> dispatch -> kernels); None
     # keeps the shared default, so model and kernels still agree
@@ -181,8 +181,9 @@ class TMExecutor:
                 srcs = [[None if s in streamed else bufs[s]
                          for s in ins.srcs] for ins in instrs]
                 lowered = lower_chain(instrs, srcs, batch_dims,
-                                      self.interpret, segment_bytes=sb,
-                                      quarantine=self.quarantine)
+                                      segment_bytes=sb,
+                                      quarantine=self.quarantine,
+                                      declines=lowering.declines)
                 if lowered is not None:
                     claimed = (end, lowered)
                     break
@@ -209,9 +210,10 @@ class TMExecutor:
             srcs = [bufs[s] for s in ins.srcs]  # Tensor Load
             sb = self.params.segment_bytes if self.params is not None else None
             faults: list | None = [] if self.quarantine is not None else None
-            lowered = lower_instr(ins, srcs, batch_dims, self.interpret,
-                                  segment_bytes=sb,
-                                  quarantine=self.quarantine, faults=faults)
+            declines: list = []
+            lowered = lower_instr(ins, srcs, batch_dims, segment_bytes=sb,
+                                  quarantine=self.quarantine, faults=faults,
+                                  declines=declines)
             if lowered is not None:
                 val, rec = lowered
                 lowering.records.append(rec)
@@ -221,6 +223,9 @@ class TMExecutor:
             if faults:
                 reason = ("degraded to engine fallback: "
                           + "; ".join(f"{name} {why}" for name, why in faults))
+            elif declines:
+                reason = "declined: " + "; ".join(
+                    f"{name}: {why}" for name, why in declines)
             else:
                 reason = (f"no matching kernel rule (batch_dims={batch_dims})"
                           if batch_dims else "no matching kernel rule")

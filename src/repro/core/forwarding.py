@@ -27,7 +27,7 @@ from repro.core.engine import apply_map
 
 def matmul_tm(x: jnp.ndarray, w: jnp.ndarray, m: MixedRadixMap | None,
               *, use_kernel: bool = False, batch_dims: int = 0,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     """``apply_map(m, x @ w)`` with the map folded into the producer.
 
     ``use_kernel`` selects the Pallas tiled-matmul kernel whose output

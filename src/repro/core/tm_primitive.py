@@ -89,7 +89,7 @@ def bind_map(m, x, batch_dims: int = 0):
 # batch_dims the trace matcher already lifts via batch_extend_map
 def _tm_map_batcher(args, dims, *, map_json, batch_dims):
     (x,), (d,) = args, dims
-    x = batching.moveaxis(x, d, 0)
+    x = jnp.moveaxis(x, d, 0)
     return tm_map_p.bind(x, map_json=map_json,
                          batch_dims=batch_dims + 1), 0
 
@@ -131,7 +131,7 @@ def _tm_route_batcher(args, dims, *, maps_json, batch_dims):
     size = next(x.shape[d] for x, d in zip(args, dims)
                 if d is not batching.not_mapped)
     xs = [jnp.broadcast_to(x[None], (size,) + x.shape)
-          if d is batching.not_mapped else batching.moveaxis(x, d, 0)
+          if d is batching.not_mapped else jnp.moveaxis(x, d, 0)
           for x, d in zip(args, dims)]
     return tm_route_p.bind(*xs, maps_json=maps_json,
                            batch_dims=batch_dims + 1), 0
@@ -168,7 +168,7 @@ mlir.register_lowering(tm_resize_p, mlir.lower_fun(_tm_resize_impl,
 def _leading_axes_batcher(prim):
     def batcher(args, dims, **params):
         (x,), (d,) = args, dims
-        return prim.bind(batching.moveaxis(x, d, 0), **params), 0
+        return prim.bind(jnp.moveaxis(x, d, 0), **params), 0
     return batcher
 
 

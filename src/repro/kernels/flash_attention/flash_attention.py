@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.platform import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -65,7 +67,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, scale: float | None = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """q, k, v: (BH, S, D) -> (BH, S, D).  GQA repeat handled by caller."""
     BH, S, D = q.shape
     Sk = k.shape[1]
@@ -91,7 +93,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=(pallas_interpret(q) if interpret is None
+                   else interpret),
     )(q, k, v)
 
 
@@ -132,7 +135,7 @@ def _fa_decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
 
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  length: jnp.ndarray, *, scale: float | None = None,
-                 bk: int = 512, interpret: bool = True) -> jnp.ndarray:
+                 bk: int = 512, interpret: bool | None = None) -> jnp.ndarray:
     """q: (BH, 1, D); k/v: (BH, S, D); length: () valid cache length."""
     BH, S, D = k.shape
     if scale is None:
@@ -157,5 +160,6 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=(pallas_interpret(q) if interpret is None
+                   else interpret),
     )(q, k, v, lens)

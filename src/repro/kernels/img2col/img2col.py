@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.platform import pallas_interpret
+
 
 def _im2col_rows(slab, oh_b, OW, kh, kw, C, stride):
     """Assemble (oh_b·OW, kh·kw·C) patches from a VMEM row slab.
@@ -49,7 +51,7 @@ def _img2col_kernel(x_ref, o_ref, *, oh_b, OW, kh, kw, C, stride):
 
 
 def img2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1, pad: int = 0,
-            *, oh_block: int = 8, interpret: bool = True) -> jnp.ndarray:
+            *, oh_block: int = 8, interpret: bool | None = None) -> jnp.ndarray:
     """(H, W, C) -> (OH·OW, kh·kw·C). Padding applied on the host side once."""
     H, W, C = x.shape
     OH = (H + 2 * pad - kh) // stride + 1
@@ -74,21 +76,20 @@ def img2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1, pad: int = 0,
         )],
         out_specs=pl.BlockSpec((oh_b * OW, kh * kw * C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, kh * kw * C), x.dtype),
-        interpret=interpret,
+        interpret=(pallas_interpret(x) if interpret is None
+                   else interpret),
     )(xp) if slab_rows == oh_b * stride else _img2col_overlap(
         xp, OH, OW, kh, kw, C, stride, oh_b, interpret)
 
 
 def _img2col_overlap(xp, OH, OW, kh, kw, C, stride, oh_b, interpret):
-    """Overlapping-slab variant: materialize each slab by dynamic slice of a
+    """Overlapping-slab variant: read each slab as a ``pl.ds`` window of a
     full-VMEM input (single-block in_spec), still assembling patches on-chip."""
     slab_rows = kh + (oh_b - 1) * stride
 
     def kernel(x_ref, o_ref):
         i = pl.program_id(0)
-        slab = jax.lax.dynamic_slice(
-            x_ref[...], (i * oh_b * stride, 0, 0),
-            (slab_rows, x_ref.shape[1], C))
+        slab = x_ref[pl.ds(i * oh_b * stride, slab_rows)]
         o_ref[...] = _im2col_rows(slab, oh_b, OW, kh, kw, C, stride)
 
     return pl.pallas_call(
@@ -97,7 +98,8 @@ def _img2col_overlap(xp, OH, OW, kh, kw, C, stride, oh_b, interpret):
         in_specs=[pl.BlockSpec(xp.shape, lambda i: (0, 0, 0))],
         out_specs=pl.BlockSpec((oh_b * OW, kh * kw * C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, kh * kw * C), xp.dtype),
-        interpret=interpret,
+        interpret=(pallas_interpret(xp) if interpret is None
+                   else interpret),
     )(xp)
 
 
@@ -108,15 +110,14 @@ def _img2col_overlap(xp, OH, OW, kh, kw, C, stride, oh_b, interpret):
 def _conv_kernel(x_ref, w_ref, o_ref, *, oh_b, OW, kh, kw, C, stride):
     i = pl.program_id(0)
     slab_rows = kh + (oh_b - 1) * stride
-    slab = jax.lax.dynamic_slice(
-        x_ref[...], (i * oh_b * stride, 0, 0), (slab_rows, x_ref.shape[1], C))
+    slab = x_ref[pl.ds(i * oh_b * stride, slab_rows)]
     patches = _im2col_rows(slab, oh_b, OW, kh, kw, C, stride)
     o_ref[...] = jnp.dot(patches, w_ref[...],
                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, pad: int = 0,
-           *, oh_block: int = 8, interpret: bool = True) -> jnp.ndarray:
+           *, oh_block: int = 8, interpret: bool | None = None) -> jnp.ndarray:
     """Implicit-GEMM conv.  x: (H, W, C); w: (kh, kw, C, OC) -> (OH, OW, OC)."""
     H, W, C = x.shape
     kh, kw, _, OC = w.shape
@@ -136,6 +137,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, pad: int = 0,
         ],
         out_specs=pl.BlockSpec((oh_b * OW, OC), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, OC), x.dtype),
-        interpret=interpret,
+        interpret=(pallas_interpret(x) if interpret is None
+                   else interpret),
     )(xp, wm)
     return out.reshape(OH, OW, OC)

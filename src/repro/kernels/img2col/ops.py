@@ -6,18 +6,19 @@ from functools import partial
 
 import jax
 
-from repro.core.dispatch import register_rule
+from repro.core.dispatch import Decline, register_rule
 from repro.core.instr import TMOpcode
 from repro.kernels.img2col.img2col import conv2d, img2col
+from repro.platform import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("kh", "kw", "stride", "pad", "interpret"))
-def img2col_call(x, *, kh, kw, stride=1, pad=0, interpret=True):
+def img2col_call(x, *, kh, kw, stride=1, pad=0, interpret=None):
     return img2col(x, kh, kw, stride, pad, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("stride", "pad", "interpret"))
-def conv2d_call(x, w, *, stride=1, pad=0, interpret=True):
+def conv2d_call(x, w, *, stride=1, pad=0, interpret=None):
     return conv2d(x, w, stride, pad, interpret=interpret)
 
 
@@ -43,6 +44,10 @@ def _img2col_matches(ins, srcs, batch_dims, segment_bytes=None):
                          fill=ins.map_.fill)
     if expect != ins.map_:
         return None
+    if not pallas_interpret(srcs[0]):
+        # the in-VMEM patch assembly reshapes (oh·OW, kh·kw·C) tiles, a
+        # shape cast Mosaic refuses; the generic row gather takes the map
+        return Decline("patch assembly needs a shape cast Mosaic refuses")
     return "pallas.img2col"
 
 

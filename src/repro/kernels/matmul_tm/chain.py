@@ -3,32 +3,30 @@
 The hand-rolled epilogues in :mod:`repro.kernels.matmul_tm.matmul_tm`
 (transpose, pixel-shuffle, split) prove the paper's Fig. 5c forwarding at
 the engine boundary for three fixed manipulations.  This module generalizes
-them to ANY legal chain the pullback machinery of
+them to ANY legal chain the row programs of
 :mod:`repro.kernels.tm_affine.chain` can express, in both directions:
 
-* **compute→TM** (``pallas.xchain.commit``): the eqn (dot_general / conv)
-  computes into a flat VMEM scratch slab — row-blocked over the matmul's
-  ``bm`` grid for the canonical 2D dot, one whole-eqn step otherwise — and
-  the chain's grid steps then gather each output segment straight out of
-  that slab through the composed pullback (masks, epilogue operands, route
-  bands, ping-pong handoff), committing final segments to HBM.  The eqn's
-  result never materializes as a tensor.
+* **compute→TM** (``pallas.xchain.commit``): at grid step 0 the eqn
+  (dot_general / conv) computes into a VMEM slab laid out as the chain
+  source's rows; every grid step then assembles one output segment from
+  that slab through the chain's row program (masks, epilogue operands,
+  route bands), committing final segments to HBM.  The eqn's result never
+  materializes as a tensor.
 * **TM→compute** (``pallas.xchain.prologue``): the chain's grid steps
-  gather output segments into a flat VMEM scratch slab — the consumer's
-  input blocks, staged in-launch — and the last step binds the eqn with
-  that slab as the crossing operand.  The chain's output never
-  materializes.
+  assemble output segments into a VMEM slab — the consumer's input, staged
+  in-launch — and the last step binds the eqn with that slab as the
+  crossing operand.  The chain's output never materializes.
 
-Both are ONE ``pallas_call``.  Anything the signature builder or the
-pullback cannot take (non-coarse links, mixed fills, VMEM budget, scalar
-operands) declines with ``None`` and the caller runs the split path,
-bit-exact — the same decline contract as the TM-internal chain rule.
+Both are ONE ``pallas_call``.  Anything the signature builder cannot take
+(non-coarse links, mixed fills, scalar operands) declines with ``None`` and
+the caller runs the split path, bit-exact — the same decline contract as the
+TM-internal chain rule.  On a TPU the compute stage must also have a Mosaic
+lowering (a canonical 2-D ``dot_general`` whose crossing matrix the slab's
+rows tile in whole 128-lane pieces) and fit VMEM; otherwise the rule
+returns a :class:`~repro.core.dispatch.Decline` before any launch.
 
 Bit-exactness of the compute stage: re-binding the eqn's primitive inside
-an interpret-mode kernel dispatches the same XLA computation eager would,
-and row-blocking a 2D dot over whole-K row groups computes each output row
-from exactly the same dot — both verified bitwise against eager across
-int8/int32/bfloat16/float32 before this layout was chosen.
+an interpret-mode kernel dispatches the same XLA computation eager would.
 """
 
 from __future__ import annotations
@@ -37,15 +35,11 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.dispatch import register_xengine_rule
-from repro.core.engine import EW_FNS
+from repro.core.dispatch import Decline, register_xengine_rule
 from repro.core.fusion import XENGINE_PRIMS
-from repro.core.schedule import plan_segments
-from repro.kernels.tm_affine.chain import (CHAIN_VMEM_BUDGET, ChainPlan,
-                                           build_chain_plan)
+from repro.kernels.tm_affine import rows as rw
+from repro.kernels.tm_affine.chain import build_chain_plan
 
 _EXECUTABLES: dict = {}
 
@@ -53,6 +47,21 @@ _EXECUTABLES: dict = {}
 def _bind_eqn(eqn, invals):
     subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
     return eqn.primitive.bind(*subfuns, *invals, **bind_params)
+
+
+def _stage_fn(eqn, interpret: bool):
+    """The eqn as a kernel compute stage.  Mosaic's matmul accumulates in
+    32 bits: a narrower dot result is accumulated there and rounded once,
+    which is what XLA's own TPU dot does."""
+    out = jnp.dtype(eqn.outvars[0].aval.dtype)
+    if interpret or eqn.primitive.name != "dot_general" or out.itemsize >= 4:
+        return lambda *ops: _bind_eqn(eqn, ops)
+    acc = jnp.int32 if jnp.issubdtype(out, jnp.integer) else jnp.float32
+    params = dict(eqn.params, preferred_element_type=acc)
+
+    def dot(*ops):
+        return jax.lax.dot_general_p.bind(*ops, **params).astype(out)
+    return dot
 
 
 def _eqn_key(eqn) -> tuple:
@@ -63,76 +72,36 @@ def _eqn_key(eqn) -> tuple:
             tuple(sorted((k, repr(v)) for k, v in eqn.params.items())))
 
 
-def _canonical_dot_rows(eqn, lhs_shape) -> int | None:
-    """The commit stage may row-block only the canonical 2D ``(M,K)@(K,N)``
-    dot — each output row group is then the same whole-K dot eager runs.
-    Returns M, or None (whole-eqn single step)."""
-    if eqn.primitive.name != "dot_general":
-        return None
+def _canonical_dot(eqn, shapes) -> bool:
+    """A 2-D ``(M, K) @ (K, N)`` dot — the compute stage Mosaic lowers."""
+    if eqn.primitive.name != "dot_general" or len(shapes) != 2:
+        return False
     (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
-    if lb or rb or tuple(lc) != (1,) or tuple(rc) != (0,):
-        return None
-    if len(lhs_shape) != 2:
-        return None
-    return int(lhs_shape[0])
+    return (not lb and not rb and tuple(lc) == (1,) and tuple(rc) == (0,)
+            and all(len(s) == 2 for s in shapes))
 
 
-def _apply_levels(plan: ChainPlan, v, it, pp_ref):
-    """The chain gather walk shared with ``tm_affine.chain._chain_kernel``:
-    per-level mask/fill, epilogue operand gather, ping-pong handoff through
-    the VMEM scratch pair, then non-chain Route bands summed in."""
-    n_levels = len(plan.levels)
-    slot = 0
-    for li, lv in enumerate(plan.levels):
-        if lv.mask is not None:
-            ok = next(it)[...]
-            v = jnp.where(ok, v, jnp.asarray(lv.fill, dtype=v.dtype))
-        if lv.ew is not None:
-            p = next(it)[...]
-            y = next(it)[...]
-            v = EW_FNS[lv.ew](v, jnp.take(y, p.reshape(-1)).reshape(v.shape))
-        last = li == n_levels - 1 and not plan.extras
-        if pp_ref is not None and not last:
-            pp_ref[slot] = v
-            v = pp_ref[slot]
-            slot ^= 1
-    for ex in plan.extras:
-        idx = next(it)[...]
-        ok = next(it)[...] if ex.mask is not None else None
-        z = next(it)[...]
-        u = jnp.take(z, idx.reshape(-1)).reshape(v.shape)
-        if ok is not None:
-            u = jnp.where(ok, u, jnp.asarray(ex.fill, dtype=v.dtype))
-        v = v + u
-    return v
-
-
-def _const_blocks(plan: ChainPlan):
-    """(const arrays, arg layout) in the kernel's ref order after the chain
-    source — identical content to ``tm_affine.chain._chain_executable``."""
-    consts = [jnp.asarray(plan.j)]
-    layout = ["const"]
-    for lv in plan.levels:
-        if lv.mask is not None:
-            consts.append(jnp.asarray(lv.mask))
-            layout.append("const")
-        if lv.ew is not None:
-            consts.append(jnp.asarray(lv.p))
-            layout.append("const")
-            layout.append("slab")
-    for ex in plan.extras:
-        consts.append(jnp.asarray(ex.idx))
-        layout.append("const")
-        if ex.mask is not None:
-            consts.append(jnp.asarray(ex.mask))
-            layout.append("const")
-        layout.append("slab")
-    return consts, layout
-
-
-def _full_spec(shape):
-    nd = len(shape)
-    return pl.BlockSpec(tuple(shape), lambda i, *, _nd=nd: (0,) * _nd)
+def _tpu_decline(direction, eqn, op_sds, prog: rw.RowProgram,
+                 cross_shape) -> str | None:
+    """Why the crossing cannot launch on a TPU (None: it can)."""
+    if not _canonical_dot(eqn, [s for s, _ in op_sds]):
+        return (f"compute stage {eqn.primitive.name} is not a 2-D "
+                f"dot_general, which is all Mosaic lowers here")
+    # the slab's row view must be the dot's matrix with its lane axis cut
+    # into whole 128-lane rows (a reshape Mosaic performs), or the matrix
+    lanes = (prog.gathers[0].l_in if direction == "compute_to_tm"
+             else prog.L)
+    width = cross_shape[-1]
+    if lanes != width and (width % lanes or lanes % 128):
+        return (f"slab rows of {lanes} lanes do not tile the dot's "
+                f"{tuple(cross_shape)} layout")
+    staged = math.prod(cross_shape) // cross_shape[-1] \
+        * rw.row_bytes(cross_shape[-1])
+    ops = sum(2 * math.prod(s) * jnp.dtype(d).itemsize for s, d in op_sds)
+    need = rw.vmem_bytes(prog) + staged + ops
+    if need > rw.VMEM_BUDGET:
+        return f"crossing needs {need} B of VMEM (budget {rw.VMEM_BUDGET})"
+    return None
 
 
 def _commit_executable(sig, eqn, op_sds: tuple, interpret: bool):
@@ -140,99 +109,13 @@ def _commit_executable(sig, eqn, op_sds: tuple, interpret: bool):
     for a compute→TM crossing."""
     key = ("commit", sig, _eqn_key(eqn), op_sds, interpret)
     hit = _EXECUTABLES.get(key)
-    if hit is not None:
-        return hit
-    plan = build_chain_plan(sig)
-    dtype = jnp.dtype(sig.dtype)
-    rb, minor, rows = plan.row_block, plan.minor, plan.rows
-    ns = plan.n_segments
-
-    y_aval = eqn.outvars[0].aval
-    y_shape = tuple(y_aval.shape)
-    y_elems = math.prod(y_shape)
-    lhs_shape = op_sds[0][0]
-    M = _canonical_dot_rows(eqn, lhs_shape)
-    if M is not None and M > 1 and len(y_shape) == 2:
-        # the matmul's natural commit grid under the same segment budget —
-        # plan_segments guarantees the row block divides M
-        mseg = plan_segments(y_shape, dtype.itemsize, sig.segment_bytes)
-        nc, brow, ncols = mseg.n_segments, mseg.row_block, y_shape[1]
-    else:
-        nc, brow, ncols = 1, 0, 0
-
-    consts, layout = _const_blocks(plan)
-    # the slab is complete once the LAST compute step's store lands, so the
-    # first chain segment gathers in that same step (Fig. 5c overlap at the
-    # grid level): chain block indices shift by nc-1, grid = nc-1+ns
-    shift = nc - 1
-    blk = pl.BlockSpec((rb, minor),
-                       lambda i: (jnp.maximum(i - shift, 0), 0))
-    n_ops = len(op_sds)
-
-    def kernel(*refs):
-        refs = list(refs)
-        pp_ref = refs.pop() if plan.use_scratch else None
-        ys_ref = refs.pop()
-        o_ref = refs.pop()
-        op_refs = refs[:n_ops]
-        chain_refs = refs[n_ops:]
-        step = pl.program_id(0)
-
-        if nc == 1:
-            @pl.when(step == 0)
-            def _compute():
-                ys_ref[...] = _bind_eqn(
-                    eqn, [r[...] for r in op_refs]).reshape(-1)
-        else:
-            @pl.when(step < nc)
-            def _compute():
-                a = op_refs[0][pl.ds(step * brow, brow), :]
-                rest = [r[...] for r in op_refs[1:]]
-                yb = _bind_eqn(eqn, [a, *rest])
-                ys_ref[pl.ds(step * brow * ncols, brow * ncols)] = \
-                    yb.reshape(-1)
-
-        @pl.when(step >= shift)   # the slab is complete from step nc-1 on
-        def _chain():
-            it = iter(chain_refs)
-            j = next(it)[...]
-            v = jnp.take(ys_ref[...], j.reshape(-1)).reshape(j.shape)
-            o_ref[...] = _apply_levels(plan, v, it, pp_ref)
-
-    scratch = [pltpu.VMEM((y_elems,), jnp.dtype(y_aval.dtype))]
-    if plan.use_scratch:
-        scratch.append(pltpu.VMEM(plan.scratch_shape, dtype))
-
-    def call(*ops_and_slabs):
-        ops = ops_and_slabs[:n_ops]
-        slabs = ops_and_slabs[n_ops:]
-        args = list(ops)
-        specs = [_full_spec(o.shape) for o in ops]
-        ci = si = 0
-        for kind in layout:
-            if kind == "const":
-                args.append(consts[ci])
-                specs.append(blk)
-                ci += 1
-            else:
-                slab = slabs[si].reshape(-1)
-                args.append(slab)
-                specs.append(pl.BlockSpec((slab.size,), lambda i: (0,)))
-                si += 1
-        out = pl.pallas_call(
-            kernel,
-            grid=(shift + ns,),
-            in_specs=specs,
-            out_specs=blk,
-            out_shape=jax.ShapeDtypeStruct((rows, minor), dtype),
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(*args)
-        return out.reshape(sig.out_shape)
-
-    built = (jax.jit(call), plan, shift + ns)
-    _EXECUTABLES[key] = built
-    return built
+    if hit is None:
+        plan = build_chain_plan(sig)
+        head = rw.Head(fn=_stage_fn(eqn, interpret), n_ops=len(op_sds))
+        call = _with_tables(rw.build_call(plan.program, interpret, head=head),
+                            rw.address_tables(plan.program, head=True))
+        hit = _EXECUTABLES[key] = (call, plan, plan.n_segments)
+    return hit
 
 
 def _prologue_executable(sig, eqn, op_sds: tuple, cross_pos: int,
@@ -241,88 +124,30 @@ def _prologue_executable(sig, eqn, op_sds: tuple, cross_pos: int,
     segments) for a TM→compute crossing."""
     key = ("prologue", sig, _eqn_key(eqn), op_sds, cross_pos, interpret)
     hit = _EXECUTABLES.get(key)
-    if hit is not None:
-        return hit
-    plan = build_chain_plan(sig)
-    dtype = jnp.dtype(sig.dtype)
-    rb, minor, rows = plan.row_block, plan.minor, plan.rows
-    ns = plan.n_segments
-    cross_shape = sig.out_shape
-    x_elems = rows * minor
+    if hit is None:
+        plan = build_chain_plan(sig)
+        out_aval = eqn.outvars[0].aval
+        stage = _stage_fn(eqn, interpret)
 
-    out_aval = eqn.outvars[0].aval
-    consts, layout = _const_blocks(plan)
-    blk = pl.BlockSpec((rb, minor), lambda i: (i, 0))
-    n_ops = len(op_sds)
-    n_other = n_ops - 1
+        def compute(xv, *others):
+            invals = list(others)
+            invals.insert(cross_pos, xv)
+            return stage(*invals)
 
-    def kernel(*refs):
-        refs = list(refs)
-        pp_ref = refs.pop() if plan.use_scratch else None
-        xs_ref = refs.pop()
-        o_ref = refs.pop()
-        other_refs = refs[len(refs) - n_other:] if n_other else []
-        xf_ref = refs[0]
-        it = iter(refs[1:len(refs) - n_other])
-        step = pl.program_id(0)
+        sink = rw.Sink(fn=compute, n_ops=len(op_sds) - 1,
+                       out_shape=tuple(out_aval.shape),
+                       out_dtype=out_aval.dtype)
+        call = _with_tables(rw.build_call(plan.program, interpret, sink=sink),
+                            rw.address_tables(plan.program))
+        hit = _EXECUTABLES[key] = (call, plan, plan.n_segments)
+    return hit
 
-        # prologue stage: one chain output segment per step, staged into
-        # the consumer's input slab in VMEM (never stored to HBM)
-        j = next(it)[...]
-        v = jnp.take(xf_ref[...], j.reshape(-1)).reshape(j.shape)
-        v = _apply_levels(plan, v, it, pp_ref)
-        xs_ref[pl.ds(step * rb * minor, rb * minor)] = v.reshape(-1)
 
-        @pl.when(step == ns - 1)
-        def _compute():
-            xv = xs_ref[...].reshape(cross_shape)
-            invals = []
-            oi = 0
-            for pos in range(n_ops):
-                if pos == cross_pos:
-                    invals.append(xv)
-                else:
-                    invals.append(other_refs[oi][...])
-                    oi += 1
-            o_ref[...] = _bind_eqn(eqn, invals)
-
-    scratch = [pltpu.VMEM((x_elems,), dtype)]
-    if plan.use_scratch:
-        scratch.append(pltpu.VMEM(plan.scratch_shape, dtype))
-
-    def call(x, *slabs_and_ops):
-        slabs = slabs_and_ops[:len(slabs_and_ops) - n_other]
-        others = slabs_and_ops[len(slabs_and_ops) - n_other:]
-        args = [x.reshape(-1)]
-        specs = [pl.BlockSpec((x.size,), lambda i: (0,))]
-        ci = si = 0
-        for kind in layout:
-            if kind == "const":
-                args.append(consts[ci])
-                specs.append(blk)
-                ci += 1
-            else:
-                slab = slabs[si].reshape(-1)
-                args.append(slab)
-                specs.append(pl.BlockSpec((slab.size,), lambda i: (0,)))
-                si += 1
-        for o in others:
-            args.append(o)
-            specs.append(_full_spec(o.shape))
-        return pl.pallas_call(
-            kernel,
-            grid=(ns,),
-            in_specs=specs,
-            out_specs=_full_spec(tuple(out_aval.shape)),
-            out_shape=jax.ShapeDtypeStruct(tuple(out_aval.shape),
-                                           out_aval.dtype),
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(*args)
-
-    built = (jax.jit(call), plan, ns)
-    _EXECUTABLES[key] = built
-    return built
+def _with_tables(call, tabs):
+    """Jit ``call`` and feed it the program's address tables as operands."""
+    fn = jax.jit(call)
+    tabs = tabs[:3] + (None,)
+    return lambda *operands: fn(tabs, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -338,17 +163,6 @@ def _sds(arrays) -> tuple:
     # hot path: arrays are jnp arrays / ShapeDtypeStructs, both carry .dtype
     # — no asarray materialization for a cache key
     return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
-
-
-def _budget_bytes(sig, eqn_srcs, slabs, staged_elems: int,
-                  staged_itemsize: int) -> int:
-    n = sum(a.size * a.dtype.itemsize for a in eqn_srcs if a is not None)
-    for s in slabs:
-        n += s.size * s.dtype.itemsize
-    out_elems = math.prod(sig.out_shape)
-    n += 4 * out_elems * (1 + len(sig.links))   # pullback constants
-    n += staged_elems * staged_itemsize         # the crossing VMEM slab
-    return n
 
 
 def _xengine_lower(direction, eqn_node, eqn_srcs, instrs, tm_srcs,
@@ -374,12 +188,13 @@ def _xengine_lower(direction, eqn_node, eqn_srcs, instrs, tm_srcs,
         sig, slabs = _chain_sig_build(instrs, srcs, 0, segment_bytes)
         if sig is None:
             return None
-        if _budget_bytes(sig, eqn_srcs, slabs, stand_in.size,
-                         jnp.dtype(y_aval.dtype).itemsize) \
-                > CHAIN_VMEM_BUDGET:
-            return None
-        fn, plan, segs = _commit_executable(sig, eqn, _sds(eqn_srcs),
-                                            interpret)
+        op_sds = _sds(eqn_srcs)
+        if not interpret:
+            why = _tpu_decline(direction, eqn, op_sds,
+                               build_chain_plan(sig).program, stand_in.shape)
+            if why is not None:
+                return Decline(why)
+        fn, plan, segs = _commit_executable(sig, eqn, op_sds, interpret)
         return fn(*eqn_srcs, *slabs), "pallas.xchain.commit", segs
 
     if direction == "tm_to_compute":
@@ -400,10 +215,6 @@ def _xengine_lower(direction, eqn_node, eqn_srcs, instrs, tm_srcs,
                 or jnp.dtype(a_aval.dtype) != jnp.dtype(sig.dtype):
             return None
         x = tm_srcs[0][0]
-        if _budget_bytes(sig, [x, *others], slabs,
-                         math.prod(sig.out_shape),
-                         jnp.dtype(sig.dtype).itemsize) > CHAIN_VMEM_BUDGET:
-            return None
         op_sds = _sds([x if i == cross_pos else eqn_srcs[i]
                        for i in range(len(eqn_srcs))])
         # the crossing slot's shape/dtype in the cache key comes from the
@@ -412,6 +223,11 @@ def _xengine_lower(direction, eqn_node, eqn_srcs, instrs, tm_srcs,
             ((tuple(sig.out_shape), str(jnp.dtype(sig.dtype)))
              if i == cross_pos else op_sds[i])
             for i in range(len(op_sds)))
+        if not interpret:
+            why = _tpu_decline(direction, eqn, op_sds,
+                               build_chain_plan(sig).program, sig.out_shape)
+            if why is not None:
+                return Decline(why)
         fn, plan, segs = _prologue_executable(sig, eqn, op_sds, cross_pos,
                                               interpret)
         return fn(x, *slabs, *others), "pallas.xchain.prologue", segs

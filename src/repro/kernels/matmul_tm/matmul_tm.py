@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.platform import pallas_interpret
+
 
 def block_div(n: int, b: int) -> int:
     """Largest block size <= ``b`` that divides ``n`` (>= 1) — the divisor
@@ -61,7 +63,7 @@ def matmul_tm(x: jnp.ndarray, w: jnp.ndarray, *,
               out_block: tuple[int, ...] | None = None,
               local_fn: Callable | None = None,
               bm: int = 128, bn: int = 128, bk: int = 128,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     """``TM(x @ w)`` with the TM op folded into the output store path.
 
     Defaults to the identity epilogue (plain tiled matmul).  ``out_index_map``
@@ -92,7 +94,8 @@ def matmul_tm(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec(out_block, out_index_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=(pallas_interpret(x) if interpret is None
+                   else interpret),
     )(x, w)
 
 

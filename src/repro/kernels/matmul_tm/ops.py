@@ -13,7 +13,7 @@ from repro.kernels.matmul_tm.matmul_tm import (
 
 
 @partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def matmul_call(x, w, *, bm=128, bn=128, bk=128, interpret=True):
+def matmul_call(x, w, *, bm=128, bn=128, bk=128, interpret=None):
     M, K = x.shape
     N = w.shape[1]
     # divisor clamp, not just min: odd dims above the block default (e.g.
@@ -23,7 +23,7 @@ def matmul_call(x, w, *, bm=128, bn=128, bk=128, interpret=True):
 
 
 @partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def matmul_transpose_call(x, w, *, bm=128, bn=128, bk=128, interpret=True):
+def matmul_transpose_call(x, w, *, bm=128, bn=128, bk=128, interpret=None):
     M, K = x.shape
     N = w.shape[1]
     bm, bn, bk = block_div(M, bm), block_div(N, bn), block_div(K, bk)
@@ -32,7 +32,7 @@ def matmul_transpose_call(x, w, *, bm=128, bn=128, bk=128, interpret=True):
 
 
 @partial(jax.jit, static_argnames=("H", "W", "C", "s", "bk", "interpret"))
-def matmul_pixel_shuffle_call(x, w, *, H, W, C, s, bk=128, interpret=True):
+def matmul_pixel_shuffle_call(x, w, *, H, W, C, s, bk=128, interpret=None):
     """(H·W, K) @ (K, C·s²) committed directly as the (H·s, W·s, C) image."""
     K = x.shape[1]
     ep = pixel_shuffle_epilogue(H, W, C, s)
@@ -54,7 +54,7 @@ def _dot_node(M: int, K: int, N: int, dtype_str: str):
 
 
 def matmul_tm_call(x: jnp.ndarray, w: jnp.ndarray, m: MixedRadixMap, *,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool | None = None) -> jnp.ndarray:
     """Generic entry: ``m(x @ w)`` as ONE launch via the cross-engine chain
     registry (the matmul commits through the composed chain map), with the
     bespoke transpose epilogue kept for its exact case, and matmul followed
@@ -71,7 +71,7 @@ def matmul_tm_call(x: jnp.ndarray, w: jnp.ndarray, m: MixedRadixMap, *,
         node = _dot_node(M, K, N, str(x.dtype))
         ins = TMInstr(opcode=TMOpcode.COARSE, srcs=("y",), dst="z", map_=m)
         lowered = lower_xengine("compute_to_tm", node, [x, w], [ins],
-                                [[None]], interpret)
+                                [[None]])
         if lowered is not None:
             return lowered[0]
     y = matmul_call(x, w, interpret=interpret)

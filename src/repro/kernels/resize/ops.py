@@ -4,13 +4,14 @@ from functools import partial
 
 import jax
 
-from repro.core.dispatch import register_rule
+from repro.core.dispatch import Decline, register_rule
 from repro.core.instr import TMOpcode
 from repro.kernels.resize.resize import resize_bilinear
+from repro.platform import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("out_h", "out_w", "interpret"))
-def resize_call(x, *, out_h, out_w, interpret=True):
+def resize_call(x, *, out_h, out_w, interpret=None):
     return resize_bilinear(x, out_h, out_w, interpret=interpret)
 
 
@@ -23,6 +24,9 @@ def _resize_matches(ins, srcs, batch_dims, segment_bytes=None):
         return None
     if len(srcs) != 1 or srcs[0].ndim != 3:
         return None
+    if not pallas_interpret(srcs[0]):
+        return Decline("rank-1 tap-table blocks break the TPU block-shape "
+                       "rule")
     return "pallas.resize"
 
 

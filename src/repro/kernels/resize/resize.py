@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.platform import pallas_interpret
+
 
 def _resize_kernel(x_ref, y0_ref, y1_ref, wy_ref, x0_ref, x1_ref, wx_ref, o_ref):
     x = x_ref[...]              # (H, W, C) slab
@@ -36,7 +38,7 @@ def _resize_kernel(x_ref, y0_ref, y1_ref, wy_ref, x0_ref, x1_ref, wx_ref, o_ref)
 
 
 def resize_bilinear(x: jnp.ndarray, out_h: int, out_w: int, *,
-                    row_block: int = 32, interpret: bool = True) -> jnp.ndarray:
+                    row_block: int = 32, interpret: bool | None = None) -> jnp.ndarray:
     """(H, W, C) -> (out_h, out_w, C), half-pixel convention."""
     H, W, C = x.shape
     ys = (jnp.arange(out_h, dtype=jnp.float32) + 0.5) * (H / out_h) - 0.5
@@ -63,5 +65,6 @@ def resize_bilinear(x: jnp.ndarray, out_h: int, out_w: int, *,
         ],
         out_specs=pl.BlockSpec((rb, out_w, C), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((out_h, out_w, C), x.dtype),
-        interpret=interpret,
+        interpret=(pallas_interpret(x) if interpret is None
+                   else interpret),
     )(x, y0, y1, wy, x0, x1, wx)

@@ -4,29 +4,29 @@ import math
 from functools import lru_cache, partial
 
 import jax
+import numpy as np
 
 from repro.core.dispatch import register_chain_rule, register_rule
 from repro.core.instr import TMOpcode
 from repro.kernels.rme_gather.rme_gather import (assemble, assemble_batched,
-                                                 evaluate, evaluate_batched,
-                                                 evaluate_chained)
+                                                 evaluate, evaluate_batched)
 
 
 @partial(jax.jit, static_argnames=("capacity", "cmp", "score_index", "interpret"))
 def evaluate_call(x, threshold, *, capacity, cmp="ge", score_index=0,
-                  interpret=True):
+                  interpret=None):
     return evaluate(x, threshold, capacity, cmp=cmp, score_index=score_index,
                     interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("capacity", "interpret"))
-def assemble_call(x, mask, *, capacity, interpret=True):
+def assemble_call(x, mask, *, capacity, interpret=None):
     return assemble(x, mask, capacity, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("capacity", "cmp", "score_index", "interpret"))
 def evaluate_batched_call(x, threshold, *, capacity, cmp="ge", score_index=0,
-                          interpret=True):
+                          interpret=None):
     """(…, N, D) record streams: leading axes flatten onto the kernel grid."""
     batch = x.shape[:-2]
     rows, idx, cnt = evaluate_batched(
@@ -38,7 +38,7 @@ def evaluate_batched_call(x, threshold, *, capacity, cmp="ge", score_index=0,
 
 
 @partial(jax.jit, static_argnames=("capacity", "interpret"))
-def assemble_batched_call(x, mask, *, capacity, interpret=True):
+def assemble_batched_call(x, mask, *, capacity, interpret=None):
     batch = x.shape[:-2]
     packed, cnt = assemble_batched(
         x.reshape((-1,) + x.shape[-2:]), mask.reshape((-1,) + mask.shape[-1:]),
@@ -109,9 +109,10 @@ def _rme_segments(ins, srcs, batch_dims, segment_bytes=None):
 
 
 # ---------------------------------------------------------------------------
-# chain rule: coarse pre-links pulled back into the evaluate kernel's load —
-# the record stream is gathered from the chain input slab and compacted in
-# one launch (detect tails: layout Rearrange/reshape + Bboxcal as one kernel)
+# chain rule: coarse pre-links that only re-lay the record stream out (a
+# reshape of the raw head grid into records) cost nothing in the evaluate
+# kernel's load — the stream is the producer's buffer viewed as records, and
+# the tail is ONE launch whose record stream never materializes
 # ---------------------------------------------------------------------------
 
 def _chain_eval_maps(instrs, srcs, batch_dims):
@@ -149,54 +150,31 @@ def _chain_eval_maps(instrs, srcs, batch_dims):
 
 
 @lru_cache(maxsize=256)
-def _chain_eval_pullback(maps):
-    """(idx, ok, fill) constants on the stream grid, or None on mixed fills
-    (a permanent decline — cached, so repeat executor runs stay cheap)."""
+def _layout_identity(maps) -> bool:
+    """True when the pre-links only re-lay the data out: the pullback reads
+    every stream element from the same flat position, nothing out of
+    bounds (a permanent property — cached, so repeat runs stay cheap)."""
     from repro.kernels.tm_affine.chain import fold_pullback
     try:
-        J, OK, fill = fold_pullback(maps)
+        J, OK, _ = fold_pullback(maps)
     except ValueError:
-        return None
-    stream = maps[-1].out_shape
-    N, D = stream[-2], stream[-1]
-    idx = jax.numpy.asarray(J.reshape(-1, N, D))
-    ok = None if OK is None else jax.numpy.asarray(OK.reshape(-1, N, D))
-    return idx, ok, fill
+        return False
+    return OK is None and bool((J == np.arange(J.size)).all())
 
 
 def _chain_eval_lower(instrs, srcs, batch_dims, interpret,
                       segment_bytes=None):
     """Single-pass chained-evaluate lowering, or None."""
-    from repro.kernels.tm_affine.chain import CHAIN_VMEM_BUDGET
     maps, _ = _chain_eval_maps(instrs, srcs, batch_dims)
-    if maps is None:
+    if maps is None or not _layout_identity(maps):
         return None
     x = srcs[0][0]
-    stream_elems = math.prod(maps[-1].out_shape)
-    # the chain slab plus the pullback index/mask constants must stay
-    # VMEM-resident for the launch — same legality rule as tm_affine.chain
-    if x.size * x.dtype.itemsize + 8 * stream_elems > CHAIN_VMEM_BUDGET:
-        return None
-    pulled = _chain_eval_pullback(maps)
-    if pulled is None:
-        return None
-    idx, ok, fill = pulled
     cfg = instrs[-1].rme
     stream = maps[-1].out_shape
-    rows, _, _ = evaluate_chained_call(
-        x, idx, ok, fill, cfg.threshold, capacity=cfg.capacity,
+    rows, _, _ = evaluate_batched_call(
+        x.reshape(stream), cfg.threshold, capacity=cfg.capacity,
         cmp=cfg.cmp, score_index=cfg.score_index, interpret=interpret)
-    val = rows.reshape(stream[:-2] + rows.shape[1:])
-    return val, "pallas.chain+rme.evaluate", max(1, math.prod(stream[:-2]))
-
-
-@partial(jax.jit, static_argnames=("fill", "capacity", "cmp", "score_index",
-                                  "interpret"))
-def evaluate_chained_call(x, idx, ok, fill, threshold, *, capacity,
-                          cmp="ge", score_index=0, interpret=True):
-    return evaluate_chained(x, idx, ok, fill, threshold, capacity,
-                            cmp=cmp, score_index=score_index,
-                            interpret=interpret)
+    return rows, "pallas.chain+rme.evaluate", max(1, math.prod(stream[:-2]))
 
 
 register_rule("rme_gather.evaluate", _evaluate_matches, _evaluate_run,

@@ -1,12 +1,17 @@
 """RME compaction Pallas kernel — assemble/evaluate on TPU.
 
-The masking crossbar of the paper's RME has no lane-shuffle analogue on TPU;
-the idiomatic equivalent is *sort-based compaction*: a stable argsort on the
-inverted mask moves surviving records to the front in original order, in one
-vectorized pass.  The kernel fuses: score -> predicate -> compaction ->
-gather, producing a statically shaped packed block (the commit buffer) plus
-a survivor count — this is Bboxcal (paper Fig. 2c) end to end, and the same
-configuration drives MoE token dispatch.
+The masking crossbar of the paper's RME streams records past a predicate and
+commits the survivors, in order, to a packed buffer.  The kernel does
+exactly that: one grid step per record stream, a scalar loop over the
+stream's records (the predicate inputs sit in SMEM), and each survivor's
+record row copied to the next free slot of the commit buffer while it has
+room.  The result is a statically shaped packed block plus a survivor count
+— Bboxcal (paper Fig. 2c) end to end, and the same configuration drives MoE
+token dispatch.
+
+Records are laid out as ``(rows, 1, D)``: the leading axis is untiled, so a
+record is addressed by a dynamic scalar index, which is what Mosaic lowers
+(it has no sort and no flat gather).
 """
 
 from __future__ import annotations
@@ -16,243 +21,111 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.platform import pallas_interpret
+
+_CMP = {"ge": lambda s, t: s >= t, "gt": lambda s, t: s > t,
+        "le": lambda s, t: s <= t, "lt": lambda s, t: s < t}
 
 
-def _evaluate_kernel(x_ref, thr_ref, o_ref, idx_ref, cnt_ref, *,
-                     cmp: str, score_index: int, capacity: int):
-    x = x_ref[...]                       # (N, D)
-    n = x.shape[0]
-    thr = thr_ref[0]
-    # compare at the promoted dtype (matches rme.evaluate's weak-typed
-    # python-float threshold: int records compare in float, not truncated)
-    scores = x[:, score_index].astype(thr.dtype)
-    mask = {
-        "ge": scores >= thr, "gt": scores > thr,
-        "le": scores <= thr, "lt": scores < thr,
-    }[cmp]
-    # stable sort: survivors first, original order preserved
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True).astype(jnp.int32)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    take = order[:capacity]
-    rows = jnp.take(x, take, axis=0)
-    live = (jnp.arange(capacity) < cnt)
-    o_ref[...] = jnp.where(live[:, None], rows, jnp.zeros_like(rows))
-    idx_ref[...] = jnp.where(live, take, n).astype(jnp.int32)
-    cnt_ref[...] = jnp.minimum(cnt, capacity).reshape(1)
+def _compact_kernel(*refs, n: int, capacity: int, keep):
+    """Pack the records whose ``keep(i)`` holds, in order, up to capacity."""
+    *pred_refs, x_ref, o_ref, idx_ref, cnt_ref = refs
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def fill_idx(j, carry):
+        idx_ref[0, 0, j] = n
+        return carry
+
+    jax.lax.fori_loop(0, capacity, fill_idx, 0)
+
+    def body(i, cnt):
+        take = keep(pred_refs, i) & (cnt < capacity)
+
+        @pl.when(take)
+        def _commit():
+            o_ref[cnt] = x_ref[i]
+            idx_ref[0, 0, cnt] = i
+
+        return cnt + take.astype(jnp.int32)
+
+    cnt_ref[0, 0, 0] = jax.lax.fori_loop(0, n, body, jnp.int32(0))
 
 
-def evaluate(x: jnp.ndarray, threshold, capacity: int, *, cmp: str = "ge",
-             score_index: int = 0, interpret: bool = True):
-    """Threshold-filter rows of (N, D) -> packed (capacity, D) + idx + count."""
-    N, D = x.shape
-    kern = functools.partial(_evaluate_kernel, cmp=cmp,
-                             score_index=score_index, capacity=capacity)
-    thr = jnp.asarray([threshold], dtype=jnp.result_type(x.dtype, threshold))
-    return pl.pallas_call(
+def _compact(x, pred_args, keep, capacity: int, interpret):
+    """(B, N, D) records + per-stream SMEM predicate inputs -> packed
+    (B, capacity, D), (B, capacity) source indices, (B, 1) counts."""
+    B, N, D = x.shape
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    pred_specs = [smem((1, 1, N), lambda b: (b, 0, 0)) if a.ndim == 3
+                  else smem(a.shape, lambda b: (0,)) for a in pred_args]
+    kern = functools.partial(_compact_kernel, n=N, capacity=capacity,
+                             keep=keep)
+    rows, idx, cnt = pl.pallas_call(
         kern,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((N, D), lambda i: (0, 0)),
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=[
-            pl.BlockSpec((capacity, D), lambda i: (0, 0)),
-            pl.BlockSpec((capacity,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity, D), x.dtype),
-            jax.ShapeDtypeStruct((capacity,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, thr)
-
-
-def _evaluate_batched_kernel(x_ref, thr_ref, o_ref, idx_ref, cnt_ref, *,
-                             cmp: str, score_index: int, capacity: int):
-    # one grid step = one record stream of the batch (block (1, N, D))
-    x = x_ref[0]
-    n = x.shape[0]
-    thr = thr_ref[0]
-    scores = x[:, score_index].astype(thr.dtype)  # promoted compare (see
-    #                                               _evaluate_kernel)
-    mask = {
-        "ge": scores >= thr, "gt": scores > thr,
-        "le": scores <= thr, "lt": scores < thr,
-    }[cmp]
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True).astype(jnp.int32)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    take = order[:capacity]
-    rows = jnp.take(x, take, axis=0)
-    live = (jnp.arange(capacity) < cnt)
-    o_ref[0] = jnp.where(live[:, None], rows, jnp.zeros_like(rows))
-    idx_ref[0] = jnp.where(live, take, n).astype(jnp.int32)
-    cnt_ref[...] = jnp.minimum(cnt, capacity).reshape(1, 1)
+        grid=(B,),
+        in_specs=pred_specs + [pl.BlockSpec((N, 1, D), lambda b: (b, 0, 0))],
+        out_specs=[pl.BlockSpec((capacity, 1, D), lambda b: (b, 0, 0)),
+                   smem((1, 1, capacity), lambda b: (b, 0, 0)),
+                   smem((1, 1, 1), lambda b: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B * capacity, 1, D), x.dtype),
+                   jax.ShapeDtypeStruct((B, 1, capacity), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, 1), jnp.int32)],
+        interpret=(pallas_interpret(x) if interpret is None
+                   else interpret),
+    )(*pred_args, x.reshape(B * N, 1, D))
+    return (rows.reshape(B, capacity, D), idx.reshape(B, capacity),
+            cnt.reshape(B, 1))
 
 
 def evaluate_batched(x: jnp.ndarray, threshold, capacity: int, *,
                      cmp: str = "ge", score_index: int = 0,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Batched evaluate: (B, N, D) -> (B, capacity, D) + idx + counts.
 
     The compaction grid is lifted over the leading axis — one grid step per
-    record stream, each an independent sort-based compaction (the paper's
-    RME run once per stream, exactly like the unbatched kernel B times but
-    in one launch)."""
+    record stream.  Scores compare at the promoted dtype (the weak-typed
+    python-float threshold: int records compare in float, not truncated);
+    that compare is exact in float32, where the kernel evaluates it."""
     B, N, D = x.shape
-    kern = functools.partial(_evaluate_batched_kernel, cmp=cmp,
-                             score_index=score_index, capacity=capacity)
-    thr = jnp.asarray([threshold], dtype=jnp.result_type(x.dtype, threshold))
-    return pl.pallas_call(
-        kern,
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, N, D), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1,), lambda b: (0,))],
-        out_specs=[
-            pl.BlockSpec((1, capacity, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, capacity), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, capacity, D), x.dtype),
-            jax.ShapeDtypeStruct((B, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, thr)
+    dt = jnp.result_type(x.dtype, threshold)
+    scores = x[:, :, score_index].astype(dt).astype(jnp.float32)
+    thr = jnp.asarray([threshold], dtype=dt).astype(jnp.float32)
+
+    def keep(refs, i):
+        s_ref, t_ref = refs
+        return _CMP[cmp](s_ref[0, 0, i], t_ref[0])
+
+    return _compact(x, [scores.reshape(B, 1, N), thr], keep, capacity,
+                    interpret)
 
 
-def _evaluate_chain_kernel(*refs, cmp: str, score_index: int, capacity: int,
-                           has_mask: bool, fill: float):
-    """Chained evaluate: the record stream is gathered from the chain input
-    slab (coarse pre-links pulled back to the stream grid) and compacted in
-    the same pass — the producer's output never exists outside VMEM."""
-    if has_mask:
-        x_ref, idx_ref, ok_ref, thr_ref, o_ref, idx_out_ref, cnt_ref = refs
-    else:
-        x_ref, idx_ref, thr_ref, o_ref, idx_out_ref, cnt_ref = refs
-    idx = idx_ref[0]                      # (N, D) pullback into the slab
-    x = jnp.take(x_ref[...], idx.reshape(-1)).reshape(idx.shape)
-    if has_mask:
-        x = jnp.where(ok_ref[0], x, jnp.asarray(fill, dtype=x.dtype))
-    n = x.shape[0]
-    thr = thr_ref[0]
-    scores = x[:, score_index].astype(thr.dtype)
-    mask = {
-        "ge": scores >= thr, "gt": scores > thr,
-        "le": scores <= thr, "lt": scores < thr,
-    }[cmp]
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True).astype(jnp.int32)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    take = order[:capacity]
-    rows = jnp.take(x, take, axis=0)
-    live = (jnp.arange(capacity) < cnt)
-    o_ref[0] = jnp.where(live[:, None], rows, jnp.zeros_like(rows))
-    idx_out_ref[0] = jnp.where(live, take, n).astype(jnp.int32)
-    cnt_ref[...] = jnp.minimum(cnt, capacity).reshape(1, 1)
-
-
-def evaluate_chained(x_slab: jnp.ndarray, idx: jnp.ndarray,
-                     ok: jnp.ndarray | None, fill: float, threshold,
-                     capacity: int, *, cmp: str = "ge", score_index: int = 0,
-                     interpret: bool = True):
-    """Batched evaluate fed through a coarse pullback: ``idx``/``ok`` are
-    (B, N, D) constants mapping each stream element into the flat chain
-    input ``x_slab``; one grid step gathers + compacts one stream."""
-    B, N, D = idx.shape
-    kern = functools.partial(
-        _evaluate_chain_kernel, cmp=cmp, score_index=score_index,
-        capacity=capacity, has_mask=ok is not None, fill=fill)
-    thr = jnp.asarray([threshold],
-                      dtype=jnp.result_type(x_slab.dtype, threshold))
-    xf = x_slab.reshape(-1)
-    in_specs = [pl.BlockSpec((xf.size,), lambda b: (0,)),
-                pl.BlockSpec((1, N, D), lambda b: (b, 0, 0))]
-    args = [xf, idx]
-    if ok is not None:
-        in_specs.append(pl.BlockSpec((1, N, D), lambda b: (b, 0, 0)))
-        args.append(ok)
-    in_specs.append(pl.BlockSpec((1,), lambda b: (0,)))
-    args.append(thr)
-    return pl.pallas_call(
-        kern,
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, capacity, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, capacity), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, capacity, D), x_slab.dtype),
-            jax.ShapeDtypeStruct((B, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*args)
-
-
-def _assemble_kernel(x_ref, mask_ref, o_ref, cnt_ref, *, capacity: int):
-    x = x_ref[...]
-    mask = mask_ref[...] != 0
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True).astype(jnp.int32)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    rows = jnp.take(x, order[:capacity], axis=0)
-    live = (jnp.arange(capacity) < cnt)
-    o_ref[...] = jnp.where(live[:, None], rows, jnp.zeros_like(rows))
-    cnt_ref[...] = jnp.minimum(cnt, capacity).reshape(1)
-
-
-def _assemble_batched_kernel(x_ref, mask_ref, o_ref, cnt_ref, *,
-                             capacity: int):
-    x = x_ref[0]
-    mask = mask_ref[0] != 0
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True).astype(jnp.int32)
-    cnt = jnp.sum(mask.astype(jnp.int32))
-    rows = jnp.take(x, order[:capacity], axis=0)
-    live = (jnp.arange(capacity) < cnt)
-    o_ref[0] = jnp.where(live[:, None], rows, jnp.zeros_like(rows))
-    cnt_ref[...] = jnp.minimum(cnt, capacity).reshape(1, 1)
+def evaluate(x: jnp.ndarray, threshold, capacity: int, *, cmp: str = "ge",
+             score_index: int = 0, interpret: bool | None = None):
+    """Threshold-filter rows of (N, D) -> packed (capacity, D) + idx + count."""
+    rows, idx, cnt = evaluate_batched(x[None], threshold, capacity, cmp=cmp,
+                                      score_index=score_index,
+                                      interpret=interpret)
+    return rows[0], idx[0], cnt[0]
 
 
 def assemble_batched(x: jnp.ndarray, mask: jnp.ndarray, capacity: int, *,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Batched assemble: (B, N, D) + (B, N) mask -> (B, capacity, D) + counts."""
     B, N, D = x.shape
-    kern = functools.partial(_assemble_batched_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kern,
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, N, D), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, N), lambda b: (b, 0))],
-        out_specs=[
-            pl.BlockSpec((1, capacity, D), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, capacity, D), x.dtype),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, mask.astype(jnp.int32))
+
+    def keep(refs, i):
+        return refs[0][0, 0, i] != 0
+
+    rows, _, cnt = _compact(x, [mask.astype(jnp.int32).reshape(B, 1, N)],
+                            keep, capacity, interpret)
+    return rows, cnt
 
 
 def assemble(x: jnp.ndarray, mask: jnp.ndarray, capacity: int, *,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """Pack rows of (N, D) selected by a runtime mask -> (capacity, D) + count."""
-    N, D = x.shape
-    kern = functools.partial(_assemble_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((N, D), lambda i: (0, 0)),
-                  pl.BlockSpec((N,), lambda i: (0,))],
-        out_specs=[
-            pl.BlockSpec((capacity, D), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity, D), x.dtype),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, mask.astype(jnp.int32))
+    rows, cnt = assemble_batched(x[None], mask[None], capacity,
+                                 interpret=interpret)
+    return rows[0], cnt[0]

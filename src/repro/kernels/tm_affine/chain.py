@@ -5,8 +5,8 @@ Per-instruction lowering executes a forwarding chain as N kernels with N−1
 full intermediates round-tripped through HBM.  This kernel collapses the
 chain: its grid iterates the *final* output's block iterations
 (:func:`repro.core.schedule.plan_segments` — the same segmentation the cycle
-model charges), and each grid step streams one segment through every link of
-the chain inside VMEM:
+model charges), and each grid step assembles one segment of the final output
+directly from the chain's sources:
 
 * adjacent links whose maps compose symbolically are pre-coalesced with
   :func:`repro.core.affine.compose_maps` (the fusion pass's composition,
@@ -14,16 +14,17 @@ the chain inside VMEM:
 * links that do NOT compose (splits/rational interactions, OOB fills,
   element-wise epilogues pinning a boundary) are *pulled back*: at build
   time each link's gather is composed **numerically** onto the final output
-  grid (index/validity arrays fold to constants under jit, exactly like
-  ``gather_indices``), and inside the kernel each link's segment result is
-  committed to a two-slot VMEM scratch buffer — the ping-pong pair
-  :class:`repro.compiler.allocate.ScratchPlan` reserves for streamed
-  buffers — before the next link consumes it.  The intermediate never
-  exists at tensor granularity, in HBM or anywhere else.
+  grid, and each resulting address table becomes a row gather
+  (:mod:`repro.kernels.tm_affine.rows`).  Per segment the kernel gathers the
+  chain source, applies each link's validity mask and epilogue in order, and
+  commits the result — the intermediates never exist at tensor granularity.
 
 A terminal multi-band Route (``TMInstr.maps``) is supported as the last
 link: the chain streams into its band while the remaining bands gather
-directly from their own VMEM-resident sources, summed per segment.
+directly from their own sources, summed per segment — or, for an overlay
+Route (``dynamic_update_slice``), written over the chain band wherever their
+map is in bounds.  A single coarse map is the one-link chain, which is how
+the tm_affine gather mode runs.
 """
 
 from __future__ import annotations
@@ -33,18 +34,12 @@ import math
 from functools import lru_cache
 
 import numpy as np
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.affine import MixedRadixMap, compose_maps, memoized_hash
-from repro.core.engine import EW_FNS, gather_indices
-from repro.core.schedule import ping_pong_shape, plan_segments
-
-# chain inputs (the chain source + every epilogue/band operand slab) are
-# VMEM-resident for the whole launch; decline chains whose slabs exceed this
-CHAIN_VMEM_BUDGET = 1 << 27
+from repro.core.engine import gather_indices
+from repro.core.schedule import plan_segments
+from repro.kernels.tm_affine import rows as rw
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +49,9 @@ class ChainSig:
     ``links`` are the batch-lifted ``(map, ew)`` pairs in dataflow order
     (before composition coalescing); ``route_maps``/``route_band`` describe
     an optional terminal multi-band Route, with the chain feeding band
-    ``route_band``.
+    ``route_band`` (with no links, band ``route_band`` reads the chain source
+    itself).  ``overlay`` gives the Route last-writer-wins semantics instead
+    of a band sum.
     """
 
     links: tuple[tuple[MixedRadixMap, str | None], ...]
@@ -62,67 +59,19 @@ class ChainSig:
     route_band: int = 0
     dtype: str = "float32"
     segment_bytes: int | None = None
+    overlay: bool = False
 
     def __hash__(self):
         # hashed on every executor call (executable-cache lookup) — memoize
         return memoized_hash(self, self.links, self.route_maps,
-                             self.route_band, self.dtype, self.segment_bytes)
+                             self.route_band, self.dtype, self.segment_bytes,
+                             self.overlay)
 
     @property
     def out_shape(self) -> tuple[int, ...]:
         if self.route_maps is not None:
             return self.route_maps[0].out_shape
         return self.links[-1][0].out_shape
-
-
-@dataclasses.dataclass(frozen=True)
-class _Level:
-    """One link after coalescing, pulled back onto the final output grid."""
-
-    mask: object       # np.bool_ (R, M) or None when the link cannot go OOB
-    fill: float
-    ew: str | None
-    p: object          # np.int32 (R, M) flat coords in this link's output
-    #                    layout (epilogue operand addressing); None if no ew
-
-
-@dataclasses.dataclass(frozen=True)
-class _Extra:
-    """A non-chain Route band: direct gather from its own source slab."""
-
-    idx: object        # np.int32 (R, M)
-    mask: object       # np.bool_ (R, M) or None
-    fill: float
-
-
-@dataclasses.dataclass
-class ChainPlan:
-    """Built constants + segmentation for one chain signature."""
-
-    sig: ChainSig
-    j: np.ndarray                 # (R, M) int32 — final pullback into x
-    levels: tuple[_Level, ...]
-    extras: tuple[_Extra, ...]
-    rows: int
-    minor: int
-    row_block: int
-    n_composed: int               # links eliminated by compose_maps
-
-    @property
-    def n_segments(self) -> int:
-        return self.rows // self.row_block
-
-    @property
-    def use_scratch(self) -> bool:
-        return len(self.levels) > 1 or bool(self.extras)
-
-    @property
-    def scratch_shape(self) -> tuple[int, int, int]:
-        """The ping-pong handoff pair — one streamed slot of the scratch
-        plan (2 segments), via the sizing shared with the compiler's
-        scratch allocator (``ScratchPlan.kernel_scratch_shapes``)."""
-        return ping_pong_shape(self.sig.out_shape,
-                               segment_bytes=self.sig.segment_bytes)
 
 
 @lru_cache(maxsize=256)
@@ -149,9 +98,9 @@ def _coalesce(links: tuple[tuple[MixedRadixMap, str | None], ...],
 
 
 def _np_gather(m: MixedRadixMap) -> tuple[np.ndarray, np.ndarray]:
-    flat, valid = gather_indices(m)   # concrete outside jit
-    return (np.asarray(flat, dtype=np.int32).ravel(),
-            np.asarray(valid, dtype=bool).ravel())
+    flat, valid = gather_indices(m, xp=np)   # host-side, even inside a trace
+    return (np.broadcast_to(flat, m.out_shape).astype(np.int32).ravel(),
+            np.broadcast_to(valid, m.out_shape).ravel())
 
 
 def fold_pullback(maps: tuple[MixedRadixMap, ...],
@@ -186,9 +135,140 @@ def fold_pullback(maps: tuple[MixedRadixMap, ...],
     return cur, ok, (0.0 if fill is None else fill)
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class ChainPlan:
+    """The built row program for one chain signature."""
+
+    sig: ChainSig
+    program: rw.RowProgram
+    n_composed: int               # links eliminated by compose_maps
+
+    @property
+    def n_segments(self) -> int:
+        return self.program.grid
+
+
+@dataclasses.dataclass(frozen=True)
+class _Pulled:
+    """The chain pulled back onto the final output grid (flat, numpy)."""
+
+    j: np.ndarray                               # into the chain source
+    in_shape: tuple[int, ...]                   # of the chain source
+    levels: tuple[tuple, ...]                   # (ok | None, fill, ew, p, y shape)
+    extras: tuple[tuple, ...]                   # (idx, ok, fill, z shape)
+
+
+def _pull_back(sig: ChainSig, links) -> _Pulled:
+    rm = math.prod(sig.out_shape)
+    maps_seq = [m for m, _ in links]
+    ews = [ew for _, ew in links]
+    if sig.route_maps is not None:
+        maps_seq.append(sig.route_maps[sig.route_band])
+        ews.append(None)
+    cur = np.arange(rm, dtype=np.int32)
+    rev = []
+    for m in reversed(maps_seq):
+        flat, valid = _np_gather(m)
+        ib = valid[cur]
+        rev.append((None if bool(ib.all()) else ib, float(m.fill), cur,
+                    m.out_shape))
+        cur = flat[cur]
+    rev.reverse()
+    levels = tuple((ok, fill, ew, p, shape)
+                   for (ok, fill, p, shape), ew in zip(rev, ews))
+    extras = []
+    if sig.route_maps is not None:
+        for b, m in enumerate(sig.route_maps):
+            if b != sig.route_band:
+                flat, valid = _np_gather(m)
+                extras.append((flat, valid, float(m.fill), m.in_shape))
+    return _Pulled(j=cur, in_shape=maps_seq[0].in_shape, levels=levels,
+                   extras=tuple(extras))
+
+
+def _program(sig: ChainSig, pulled: _Pulled) -> rw.RowProgram:
+    """Row program: gathers for the source, each epilogue operand and each
+    extra band; masks for each level and each band's validity."""
+    seg = plan_segments(sig.out_shape, segment_bytes=sig.segment_bytes)
+    rm = seg.rows * seg.minor
+    lv_ok = [ok for ok, *_ in pulled.levels]
+
+    def all_ok(oks):
+        out = np.ones(rm, bool)
+        for ok in oks:
+            if ok is not None:
+                out &= ok
+        return out
+
+    # (flat idx, needed elements, source shape, fill, exact validity?)
+    wants = [(pulled.j, all_ok(lv_ok), pulled.in_shape, 0.0, None)]
+    for li, (ok, fill, ew, p, shape) in enumerate(pulled.levels):
+        if ew is not None:
+            wants.append((p, all_ok(lv_ok[li + 1:]), shape, 0.0, None))
+    for idx, valid, fill, shape in pulled.extras:
+        wants.append((idx, valid, shape, fill, valid))
+
+    def gathers_at(L):
+        R = rm // L
+        out = []
+        for idx, need, shape, _, _ in wants:
+            g = rw.row_gather(idx, need, shape, R, L)
+            if g is None:
+                return None
+            out.append(g)
+        return out
+
+    found: dict[int, list] = {}
+
+    def fits(L):
+        found[L] = gathers_at(L)
+        return found[L] is not None
+
+    L = rw.choose_lanes(seg.minor, fits)
+    gathers = found.get(L) or gathers_at(L)
+    R = rm // L
+
+    masks: list[rw.RowMask] = []
+    ops: list[tuple] = [("load", 0)]
+    g = 1
+    for ok, fill, ew, p, shape in pulled.levels:
+        if ok is not None:
+            m = rw.row_mask(ok, R, L)
+            if m is not None:
+                masks.append(m)
+                ops.append(("mask", len(masks) - 1, fill))
+        if ew is not None:
+            ops.append(("ew", ew, g))
+            g += 1
+    for idx, valid, fill, shape in pulled.extras:
+        gk = gathers[g]
+        exact = rw.implicit_mask(gk, R, L).reshape(-1)
+        if sig.overlay:
+            m = rw.row_mask(valid, R, L) or rw.RowMask(
+                a=0, b=L, flags=np.ones(R, bool))
+            masks.append(m)
+            ops.append(("overlay", g, len(masks) - 1))
+        elif np.array_equal(exact, valid):
+            ops.append(("add", g))
+        else:
+            # the band copies lanes its map does not cover: re-fill them
+            masks.append(rw.row_mask(valid, R, L))
+            ops.append(("add_masked", g, len(masks) - 1, fill))
+        g += 1
+
+    fills = [w[3] for w in wants]
+    slab_of = list(range(len(wants)))
+    return rw.RowProgram(
+        out_shape=sig.out_shape, dtype=sig.dtype, L=L,
+        rb=seg.row_block * (seg.minor // L), grid=seg.n_segments,
+        gathers=tuple(gathers), fills=tuple(fills), slab_of=tuple(slab_of),
+        masks=tuple(masks), ops=tuple(ops), n_slabs=len(wants))
+
+
 @lru_cache(maxsize=256)
 def build_chain_plan(sig: ChainSig) -> ChainPlan:
-    """Pull every link back onto the final output grid.
+    """Pull every link back onto the final output grid and lay the result
+    out as one row program.
 
     Backward pass over the (coalesced) link maps: maintain ``cur``, the flat
     coordinate each final output element reads in the current link's output;
@@ -198,197 +278,26 @@ def build_chain_plan(sig: ChainSig) -> ChainPlan:
     precisely the semantics of executing the links one by one.
     """
     links = _coalesce(sig.links)
-    n_composed = len(sig.links) - len(links)
-    out_shape = sig.out_shape
-    seg = plan_segments(out_shape, segment_bytes=sig.segment_bytes)
-    rm = seg.rows * seg.minor
-
-    maps_seq = [m for m, _ in links]
-    ews_seq: list[str | None] = [ew for _, ew in links]
-    if sig.route_maps is not None:
-        maps_seq.append(sig.route_maps[sig.route_band])
-        ews_seq.append(None)
-
-    cur = np.arange(rm, dtype=np.int32)
-    rev: list[tuple[np.ndarray | None, float, np.ndarray]] = []
-    for m in reversed(maps_seq):
-        flat, valid = _np_gather(m)
-        ib = valid[cur]
-        rev.append((None if bool(ib.all()) else ib.reshape(seg.rows, seg.minor),
-                    float(m.fill), cur.reshape(seg.rows, seg.minor)))
-        cur = flat[cur]
-    rev.reverse()
-
-    levels = tuple(
-        _Level(mask=mask, fill=fill, ew=ew,
-               p=p if ew is not None else None)
-        for (mask, fill, p), ew in zip(rev, ews_seq))
-
-    extras = []
-    if sig.route_maps is not None:
-        for b, m in enumerate(sig.route_maps):
-            if b == sig.route_band:
-                continue
-            flat, valid = _np_gather(m)   # bands share the final out grid
-            extras.append(_Extra(
-                idx=flat.reshape(seg.rows, seg.minor),
-                mask=None if bool(valid.all())
-                else valid.reshape(seg.rows, seg.minor),
-                fill=float(m.fill)))
-
-    return ChainPlan(sig=sig, j=cur.reshape(seg.rows, seg.minor),
-                     levels=levels, extras=tuple(extras), rows=seg.rows,
-                     minor=seg.minor, row_block=seg.row_block,
-                     n_composed=n_composed)
-
-
-def _chain_kernel(plan: ChainPlan, dtype):
-    """Build the kernel body from the plan's static structure.
-
-    Ref order: x, j, then per level [mask][p, y], then per extra idx [mask] z,
-    then the output block, then (optionally) the ping-pong scratch."""
-    n_levels = len(plan.levels)
-
-    def kernel(*refs):
-        refs = list(refs)
-        s_ref = refs.pop() if plan.use_scratch else None
-        o_ref = refs.pop()
-        it = iter(refs)
-        xf = next(it)[...]
-        j = next(it)[...]
-        v = jnp.take(xf, j.reshape(-1)).reshape(j.shape)
-        slot = 0
-        for li, lv in enumerate(plan.levels):
-            if lv.mask is not None:
-                ok = next(it)[...]
-                v = jnp.where(ok, v, jnp.asarray(lv.fill, dtype=v.dtype))
-            if lv.ew is not None:
-                p = next(it)[...]
-                y = next(it)[...]
-                v = EW_FNS[lv.ew](v, jnp.take(y, p.reshape(-1)).reshape(v.shape))
-            last = li == n_levels - 1 and not plan.extras
-            if s_ref is not None and not last:
-                # commit this link's segment to one ping-pong slot; the next
-                # link streams it back out of VMEM — the scratch handoff
-                s_ref[slot] = v
-                v = s_ref[slot]
-                slot ^= 1
-        for ex in plan.extras:
-            idx = next(it)[...]
-            ok = next(it)[...] if ex.mask is not None else None
-            z = next(it)[...]
-            u = jnp.take(z, idx.reshape(-1)).reshape(v.shape)
-            if ok is not None:
-                u = jnp.where(ok, u, jnp.asarray(ex.fill, dtype=v.dtype))
-            v = v + u
-        o_ref[...] = v
-
-    return kernel
-
-
-@lru_cache(maxsize=256)
-def _chain_executable(sig: ChainSig, interpret: bool):
-    """Build (jitted chain callable, plan) for one signature.
-
-    The pullback constants are closed over — they fold into the jaxpr as
-    constants, exactly like ``gather_indices`` under jit."""
-    plan = build_chain_plan(sig)
-    dtype = jnp.dtype(sig.dtype)
-    rb, minor, rows = plan.row_block, plan.minor, plan.rows
-    grid = (rows // rb,)
-    blk = pl.BlockSpec((rb, minor), lambda i: (i, 0))
-
-    consts: list[jnp.ndarray] = [jnp.asarray(plan.j)]
-    const_specs: list[pl.BlockSpec] = [blk]
-    slab_slots: list[str] = []      # where each runtime slab plugs in
-    for lv in plan.levels:
-        if lv.mask is not None:
-            consts.append(jnp.asarray(lv.mask))
-            const_specs.append(blk)
-        if lv.ew is not None:
-            consts.append(jnp.asarray(lv.p))
-            const_specs.append(blk)
-            slab_slots.append("y")
-    for ex in plan.extras:
-        consts.append(jnp.asarray(ex.idx))
-        const_specs.append(blk)
-        if ex.mask is not None:
-            consts.append(jnp.asarray(ex.mask))
-            const_specs.append(blk)
-        slab_slots.append("z")
-
-    kernel = _chain_kernel(plan, dtype)
-    scratch = ([pltpu.VMEM(plan.scratch_shape, dtype)]
-               if plan.use_scratch else [])
-
-    def call(x, *slabs):
-        # interleave runtime slabs into the static arg/spec order
-        args: list[jnp.ndarray] = [x.reshape(-1)]
-        specs: list[pl.BlockSpec] = [
-            pl.BlockSpec((x.size,), lambda i: (0,))]
-        ci = si = 0
-        for spec_kind in _arg_layout(plan):
-            if spec_kind == "const":
-                args.append(consts[ci])
-                specs.append(const_specs[ci])
-                ci += 1
-            else:
-                slab = slabs[si].reshape(-1)
-                args.append(slab)
-                specs.append(pl.BlockSpec((slab.size,), lambda i: (0,)))
-                si += 1
-        out = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=specs,
-            out_specs=blk,
-            out_shape=jax.ShapeDtypeStruct((rows, minor), dtype),
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(*args)
-        return out.reshape(sig.out_shape)
-
-    return jax.jit(call), plan
-
-
-def _arg_layout(plan: ChainPlan) -> list[str]:
-    """Static arg order after x: consts and runtime slabs interleaved to
-    match the kernel's ref order."""
-    layout: list[str] = ["const"]          # j
-    for lv in plan.levels:
-        if lv.mask is not None:
-            layout.append("const")
-        if lv.ew is not None:
-            layout.append("const")         # p
-            layout.append("slab")          # y
-    for ex in plan.extras:
-        layout.append("const")             # idx
-        if ex.mask is not None:
-            layout.append("const")
-        layout.append("slab")              # z
-    return layout
+    pulled = _pull_back(sig, links)
+    return ChainPlan(sig=sig, program=_program(sig, pulled),
+                     n_composed=len(sig.links) - len(links))
 
 
 def chain_plan_of(sig: ChainSig) -> ChainPlan:
-    """Expose the built plan (segments, levels, composed count) for
-    reports/tests without building or executing a kernel."""
+    """Expose the built plan (segments, composed count) for reports/tests
+    without building or executing a kernel."""
     return build_chain_plan(sig)
+
+
+def tpu_decline(sig: ChainSig) -> str | None:
+    """Why the chain's kernel cannot launch on a TPU (None: it can)."""
+    return rw.tpu_decline(build_chain_plan(sig).program)
 
 
 def tm_chain(sig: ChainSig, x: jnp.ndarray,
              slabs: tuple[jnp.ndarray, ...] = (), *,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool) -> jnp.ndarray:
     """Execute a chain signature: ``x`` is the chain source, ``slabs`` the
     epilogue operands then non-chain Route band sources, in link order."""
-    fn, _ = _chain_executable(sig, interpret)
-    return fn(x, *slabs)
-
-
-def chain_slab_bytes(sig: ChainSig, x, slabs) -> int:
-    n = x.size * x.dtype.itemsize
-    for s in slabs:
-        n += s.size * s.dtype.itemsize
-    # pullback constants stream per segment but are VMEM-resident per step
-    plan_elems = math.prod(sig.out_shape)
-    n += 4 * plan_elems * (1 + len(sig.links))
-    return n
+    prog = build_chain_plan(sig).program
+    return rw.run(prog, (x,) + tuple(slabs), interpret=interpret)

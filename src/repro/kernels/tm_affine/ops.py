@@ -8,34 +8,29 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.affine import MixedRadixMap, batch_extend_map
-from repro.core.dispatch import register_chain_rule, register_rule
+from repro.core.dispatch import Decline, register_chain_rule, register_rule
 from repro.core.engine import EW_FNS
 from repro.core.instr import TMOpcode
 from repro.core.schedule import map_segments
-from repro.kernels.tm_affine.chain import (CHAIN_VMEM_BUDGET, ChainSig,
-                                           chain_plan_of, chain_slab_bytes,
-                                           tm_chain)
-from repro.kernels.tm_affine.tm_affine import analyze_block_mode, tm_affine
+from repro.kernels.tm_affine import chain as ch
+from repro.kernels.tm_affine.chain import ChainSig, chain_plan_of, tm_chain
+from repro.kernels.tm_affine.tm_affine import (analyze_block_mode,
+                                               gather_sig, tm_affine)
+from repro.platform import pallas_interpret
 
 
 @partial(jax.jit, static_argnums=(1,),
          static_argnames=("interpret", "force_mode", "segment_bytes"))
-def tm_affine_call(x: jnp.ndarray, m: MixedRadixMap, *, interpret: bool = True,
+def tm_affine_call(x: jnp.ndarray, m: MixedRadixMap, *,
+                   interpret: bool | None = None,
                    force_mode: str | None = None,
                    segment_bytes: int | None = None) -> jnp.ndarray:
     return tm_affine(x, m, interpret=interpret, force_mode=force_mode,
                      segment_bytes=segment_bytes)
 
 
-@partial(jax.jit, static_argnums=(2,),
-         static_argnames=("ew", "interpret", "force_mode", "segment_bytes"))
-def tm_affine_ew_call(x: jnp.ndarray, y: jnp.ndarray, m: MixedRadixMap, *,
-                      ew: str, interpret: bool = True,
-                      force_mode: str | None = None,
-                      segment_bytes: int | None = None) -> jnp.ndarray:
-    """Map + fused element-wise epilogue: ``ew(apply_map(m, x), y)``."""
-    return tm_affine(x, m, interpret=interpret, force_mode=force_mode,
-                     y=y, ew=EW_FNS[ew], segment_bytes=segment_bytes)
+def _on_tpu(*arrays) -> bool:
+    return not pallas_interpret(*arrays)
 
 
 def plan_of(m: MixedRadixMap):
@@ -50,7 +45,6 @@ def plan_of(m: MixedRadixMap):
 # MixedRadixMap is frozen/hashable: memoize the batch lift and the decode
 # analysis so match + run share one computation per (map, batch, budget)
 _lift_cached = lru_cache(maxsize=512)(batch_extend_map)
-_plan_cached = lru_cache(maxsize=512)(analyze_block_mode)
 
 
 def _lifted(ins, srcs, batch_dims) -> MixedRadixMap | None:
@@ -68,27 +62,33 @@ def _coarse_matches(ins, srcs, batch_dims, segment_bytes=None):
     m = _lifted(ins, srcs, batch_dims)
     if m is None:
         return None
-    mode = ("block" if _plan_cached(m, None, segment_bytes) is not None
+    mode = ("block" if analyze_block_mode(m, None, segment_bytes) is not None
             else "gather")
     if ins.ew is not None:
         # the kernel epilogue streams y in output layout — broadcastable
         # operands are the engine's job, decline and fall back
         if len(srcs) != 2 or srcs[1].shape != m.out_shape:
             return None
-        return f"pallas.{mode}+ew"
-    if len(srcs) != 1:
+    elif len(srcs) != 1:
         return None
-    return f"pallas.{mode}"
+    if mode == "gather" and _on_tpu(srcs[0]):
+        why = ch.tpu_decline(gather_sig(
+            m, srcs[0].dtype, ins.ew.value if ins.ew is not None else None,
+            segment_bytes))
+        if why is not None:
+            return Decline(why)
+    return f"pallas.{mode}+ew" if ins.ew is not None else f"pallas.{mode}"
 
 
 def _coarse_run(ins, srcs, batch_dims, interpret, segment_bytes=None):
+    # unjitted entry: the kernels jit themselves, and the row gathers' address
+    # tables must reach them as operands, not as constants of an outer jit
     m = _lifted(ins, srcs, batch_dims)
     if ins.ew is not None:
-        return tm_affine_ew_call(srcs[0], srcs[1], m, ew=ins.ew.value,
-                                 interpret=interpret,
-                                 segment_bytes=segment_bytes)
-    return tm_affine_call(srcs[0], m, interpret=interpret,
-                          segment_bytes=segment_bytes)
+        return tm_affine(srcs[0], m, interpret=interpret, y=srcs[1],
+                         ew=ins.ew.value, segment_bytes=segment_bytes)
+    return tm_affine(srcs[0], m, interpret=interpret,
+                     segment_bytes=segment_bytes)
 
 
 def _coarse_segments(ins, srcs, batch_dims, segment_bytes=None):
@@ -102,10 +102,7 @@ def _route_matches(ins, srcs, batch_dims, segment_bytes=None):
     if ins.opcode != TMOpcode.COARSE or ins.maps is None:
         return None
     if ins.meta and ins.meta.get("overlay"):
-        # overlay Routes (dynamic_update_slice) overwrite rather than sum —
-        # the band-sum kernel below would double-count the overlapped region,
-        # so decline and let the reference engine's where-select run it
-        return None
+        return None  # overwrite semantics: the overlay rule's
     n_band = len(ins.maps)
     expected = n_band + (1 if ins.ew is not None else 0)
     if len(srcs) != expected:
@@ -113,6 +110,15 @@ def _route_matches(ins, srcs, batch_dims, segment_bytes=None):
     for x, m in zip(srcs, ins.maps):
         if x.shape[batch_dims:] != m.in_shape:
             return None
+    if _on_tpu(srcs[0]):
+        batch = srcs[0].shape[:batch_dims]
+        for x, m in zip(srcs, ins.maps):
+            lifted = _lift_cached(m, batch)
+            if analyze_block_mode(lifted, None, segment_bytes) is None:
+                why = ch.tpu_decline(gather_sig(lifted, x.dtype, None,
+                                                segment_bytes))
+                if why is not None:
+                    return Decline(why)
     return "pallas.route+ew" if ins.ew is not None else "pallas.route"
 
 
@@ -121,12 +127,48 @@ def _route_run(ins, srcs, batch_dims, interpret, segment_bytes=None):
     batch = srcs[0].shape[:batch_dims]
     out = None
     for x, m in zip(srcs, ins.maps):
-        band = tm_affine_call(x, _lift_cached(m, batch), interpret=interpret,
-                              segment_bytes=segment_bytes)
+        band = tm_affine(x, _lift_cached(m, batch), interpret=interpret,
+                         segment_bytes=segment_bytes)
         out = band if out is None else out + band
     if ins.ew is not None:
         out = EW_FNS[ins.ew.value](out, srcs[-1])
     return out
+
+
+def _overlay_sig(ins, srcs, batch_dims, segment_bytes):
+    batch = srcs[0].shape[:batch_dims]
+    return ChainSig(links=(), route_maps=tuple(_lift_cached(m, batch)
+                                               for m in ins.maps),
+                    route_band=0, dtype=str(srcs[0].dtype),
+                    segment_bytes=segment_bytes, overlay=True)
+
+
+def _overlay_matches(ins, srcs, batch_dims, segment_bytes=None):
+    """Overlay Route (``dynamic_update_slice``): later bands overwrite the
+    base band wherever their map is in bounds — one launch, all bands."""
+    if ins.opcode != TMOpcode.COARSE or ins.maps is None \
+            or not (ins.meta and ins.meta.get("overlay")) \
+            or ins.ew is not None or len(srcs) != len(ins.maps):
+        return None
+    if any(x.shape[batch_dims:] != m.in_shape or x.dtype != srcs[0].dtype
+           for x, m in zip(srcs, ins.maps)):
+        return None
+    if _on_tpu(srcs[0]):
+        why = ch.tpu_decline(_overlay_sig(ins, srcs, batch_dims,
+                                          segment_bytes))
+        if why is not None:
+            return Decline(why)
+    return "pallas.overlay"
+
+
+def _overlay_run(ins, srcs, batch_dims, interpret, segment_bytes=None):
+    sig = _overlay_sig(ins, srcs, batch_dims, segment_bytes)
+    return tm_chain(sig, srcs[0], tuple(srcs[1:]), interpret=interpret)
+
+
+def _overlay_segments(ins, srcs, batch_dims, segment_bytes=None):
+    return chain_plan_of(_overlay_sig(ins, srcs, batch_dims,
+                                      segment_bytes)).n_segments
 
 
 def _route_segments(ins, srcs, batch_dims, segment_bytes=None):
@@ -225,8 +267,9 @@ def _chain_lower(instrs, srcs, batch_dims, interpret, segment_bytes=None):
     sig, slabs = _chain_sig_build(instrs, srcs, batch_dims, segment_bytes)
     if sig is None:
         return None
-    if chain_slab_bytes(sig, srcs[0][0], slabs) > CHAIN_VMEM_BUDGET:
-        return None  # chain inputs must stay VMEM-resident for the launch
+    why = ch.tpu_decline(sig) if _on_tpu(srcs[0][0]) else None
+    if why is not None:
+        return Decline(why)  # the per-instruction kernels take the links
     val = tm_chain(sig, srcs[0][0], slabs, interpret=interpret)
     path = ("pallas.chain+route" if sig.route_maps is not None
             else "pallas.chain")
@@ -236,6 +279,8 @@ def _chain_lower(instrs, srcs, batch_dims, interpret, segment_bytes=None):
 register_rule("tm_affine.route", _route_matches, _route_run, priority=10,
               segments=_route_segments,
               launches=lambda ins, srcs, batch_dims: len(ins.maps))
+register_rule("tm_affine.overlay", _overlay_matches, _overlay_run,
+              priority=10, segments=_overlay_segments)
 register_rule("tm_affine", _coarse_matches, _coarse_run, priority=0,
               segments=_coarse_segments)
 register_chain_rule("tm_affine.chain", _chain_lower, priority=0)

@@ -4,35 +4,39 @@ Two execution modes, selected by analyzing the :class:`MixedRadixMap` (the
 "instruction decode" step of the TMU, performed at trace time):
 
 * **block mode** — the map lifts to *block* granularity: every output block
-  is exactly one input block (possibly flipped along some axes).  Then the
-  Pallas ``BlockSpec.index_map`` IS the paper's address generator: the grid
+  is exactly one input block (possibly permuted).  Then the Pallas
+  ``BlockSpec.index_map`` IS the paper's address generator: the grid
   sequencer evaluates the affine block map each step to drive the HBM→VMEM
-  DMA, and the kernel body applies only the intra-block residual (axis
-  permutation / flips).  Covers Transpose, Rot90, Split/Route bands, Add,
-  head-layout permutes — zero index tensors, pure DMA re-addressing.
+  DMA, and the kernel body applies only the intra-block axis permutation.
+  Reversed axes are addressed at block granularity (block size 1 on an
+  untiled axis), since a reversal inside a tile has no Mosaic lowering.
+  Covers Transpose, Split/Route bands, Add, head-layout permutes — zero
+  index tensors, pure DMA re-addressing.
 
-* **gather mode** — general fallback: flat gather indices are precomputed at
-  trace time (they fold to constants under jit, exactly like loading the
-  TMU's address registers) and streamed in blocks alongside the data; the
-  kernel gathers rows from a VMEM-resident input slab.  Covers PixelShuffle,
+* **gather mode** — general fallback: the map runs as the one-link chain of
+  :mod:`repro.kernels.tm_affine.chain`, whose address tables are row
+  gathers (:mod:`repro.kernels.tm_affine.rows`) computed at trace time,
+  exactly like loading the TMU's address registers.  Covers PixelShuffle,
   Img2col, Rearrange, Upsample and any future (A, B) pair.
 
-Both modes tile the output in (8·k, 128·m)-aligned VMEM blocks.
+Block shapes obey the TPU tiling rule on both sides of the DMA: the last two
+dimensions of every block are multiples of (8, 128) or span the whole axis.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from fractions import Fraction
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.affine import MixedRadixMap
-from repro.core.engine import gather_indices
-from repro.core.schedule import CycleParams, plan_segments
+from repro.core.engine import EW_FNS
+from repro.core.schedule import CycleParams
+from repro.platform import pallas_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -57,6 +61,7 @@ class BlockPlan:
     perm: tuple[int, ...]           # in-block axis permutation for the body
 
 
+@lru_cache(maxsize=1024)
 def analyze_block_mode(m: MixedRadixMap,
                        block: tuple[int, ...] | None = None,
                        segment_bytes: int | None = None) -> BlockPlan | None:
@@ -90,7 +95,10 @@ def analyze_block_mode(m: MixedRadixMap,
         sign[out_ax] = s
         offset[out_ax] = off
     if block is None:
-        block = _default_block(m.out_shape, segment_bytes)
+        block = _legal_block(m, src_axis, sign, offset,
+                             _default_block(m.out_shape, segment_bytes))
+        if block is None:
+            return None
     grid = []
     for d, (size, bs) in enumerate(zip(m.out_shape, block)):
         if size % bs:
@@ -101,8 +109,8 @@ def analyze_block_mode(m: MixedRadixMap,
         # image is [off-(g+1)bs+1, off-g·bs] — one block iff (off+1) % bs == 0.
         if sign[d] > 0 and offset[d] % bs:
             return None
-        if sign[d] < 0 and (offset[d] + 1) % bs:
-            return None
+        if sign[d] < 0 and (bs != 1 or (offset[d] + 1) % bs):
+            return None  # no in-tile reversal: flipped axes block at 1
         if m.in_shape[src_axis[d]] % bs:
             return None
         grid.append(size // bs)
@@ -111,9 +119,66 @@ def analyze_block_mode(m: MixedRadixMap,
                      tuple(block), tuple(grid), tuple(src_axis))
 
 
+def _granule(pos: int, ndim: int) -> int:
+    """Block-size granule the TPU tiling imposes on axis ``pos`` of an
+    ``ndim``-d array: 128 lanes, 8 sublanes, anything on leading axes."""
+    if pos == ndim - 1:
+        return 128
+    if pos == ndim - 2:
+        return 8
+    return 1
+
+
+def _legal_block(m: MixedRadixMap, src_axis, sign, offset,
+                 block: tuple[int, ...]) -> tuple[int, ...] | None:
+    """Round ``block`` up so the out block and its permuted in block both
+    satisfy the tiling rule (multiple of the granule, or the whole axis);
+    flipped axes must block at 1.  None when no legal block exists."""
+    n = len(block)
+    blk = list(block)
+    for d in range(n):
+        size, in_size = m.out_shape[d], m.in_shape[src_axis[d]]
+        g = math.lcm(_granule(d, n), _granule(src_axis[d], n))
+        if sign[d] < 0:
+            if g != 1:
+                return None
+            blk[d] = 1
+            continue
+        if size % g == 0 and blk[d] % g:
+            blk[d] = next(b for b in range(g, size + 1, g) if size % b == 0
+                          and b >= blk[d])
+        elif size % g:
+            if size != in_size or offset[d] != 0:
+                return None  # only a whole axis is legal, and it is not one
+            blk[d] = size
+    in_blk = [0] * n
+    for d in range(n):
+        in_blk[src_axis[d]] = blk[d]
+    out_b, in_b = _vmem_bytes(blk), _vmem_bytes(in_blk)
+    # double-buffered in + out blocks, plus the permuted value in flight
+    if 2 * (out_b + in_b) + max(out_b, in_b) > _BLOCK_VMEM_CAP:
+        return None
+    return tuple(blk)
+
+
+# what a block kernel may hold in VMEM (the compiler's default scoped limit
+# is 16 MiB on v5e); blocks that do not fit go to gather mode
+_BLOCK_VMEM_CAP = 12 << 20
+
+
+def _vmem_bytes(block) -> int:
+    """A block's VMEM footprint: its last two axes pad to whole (8, 128)
+    tiles of 4-byte words (a narrow minor axis costs the full 128 lanes)."""
+    b = list(block) or [1]
+    lanes = -(-b[-1] // 128) * 128
+    rows = -(-b[-2] // 8) * 8 if len(b) > 1 else 1
+    return math.prod(b[:-2]) * rows * lanes * 4
+
+
 def _default_block(shape: tuple[int, ...],
                    segment_bytes: int | None = None) -> tuple[int, ...]:
-    """(…, 8·k, 128·m)-aligned blocks sized to one ping-pong segment.
+    """(…, 8·k, 128·m)-aligned (or whole-axis) blocks sized to one
+    ping-pong segment.
 
     The budget is ``CycleParams.segment_bytes`` — the block IS the schedule
     pass's block iteration, so grid size == the cycle model's segment count.
@@ -124,12 +189,13 @@ def _default_block(shape: tuple[int, ...],
     itemsize = 4
     blk = list(shape)
     if len(shape) >= 1:
-        blk[-1] = min(shape[-1], 128) if shape[-1] % 128 == 0 or shape[-1] < 128 \
-            else math.gcd(shape[-1], 128)
+        blk[-1] = 128 if shape[-1] % 128 == 0 else shape[-1]
     if len(shape) >= 2:
-        blk[-2] = math.gcd(shape[-2], 256)
-        # gcd with 256 is a power of two: halving keeps it a divisor
-        while math.prod(blk[-2:]) * itemsize > budget and blk[-2] > 8:
+        blk[-2] = math.gcd(shape[-2], 256) if shape[-2] % 8 == 0 \
+            else shape[-2]
+        # a multiple of 8 dividing the axis: halving keeps both properties
+        while math.prod(blk[-2:]) * itemsize > budget and blk[-2] > 8 \
+                and blk[-2] % 16 == 0:
             blk[-2] //= 2
     for d in range(len(shape) - 3, -1, -1):
         blk[d] = 1
@@ -163,9 +229,6 @@ def _block_kernel(plan: BlockPlan, ew=None):
         # un-permute: out-block axis i <- in-block axis plan.perm[i]
         val = jnp.transpose(val, axes=plan.perm) if plan.perm != tuple(
             range(len(plan.perm))) else val
-        for ax, s in enumerate(plan.sign):
-            if s < 0:
-                val = jnp.flip(val, axis=ax)
         if ew is not None:  # fused element-wise epilogue (same pipeline pass)
             val = ew(val, rest[0][...])
         o_ref[...] = val
@@ -211,85 +274,49 @@ def _block_call(x: jnp.ndarray, m: MixedRadixMap, plan: BlockPlan,
 
 
 # ---------------------------------------------------------------------------
-# gather-mode kernel
+# gather mode: the one-link chain
 # ---------------------------------------------------------------------------
 
-def _gather_kernel(ew):
-    def kernel(x_ref, idx_ref, valid_ref, fill_ref, *rest):
-        o_ref = rest[-1]
-        xf = x_ref[...].reshape(-1)
-        idx = idx_ref[...]
-        out = jnp.take(xf, idx.reshape(-1), axis=0).reshape(idx.shape)
-        valid = valid_ref[...]
-        out = jnp.where(valid, out, fill_ref[0].astype(out.dtype))
-        if ew is not None:  # fused element-wise epilogue
-            out = ew(out, rest[0][...])
-        o_ref[...] = out
-    return kernel
-
-
-def _gather_call(x: jnp.ndarray, m: MixedRadixMap, interpret: bool,
-                 row_block: int | None = None, y: jnp.ndarray | None = None,
-                 ew=None, segment_bytes: int | None = None) -> jnp.ndarray:
-    flat_idx, valid = gather_indices(m)  # folds to constants under jit
-    # segmentation comes from the schedule pass — one grid step is one block
-    # iteration of the cycle model, by construction
-    seg = plan_segments(m.out_shape, segment_bytes=segment_bytes)
-    rows, minor = seg.rows, seg.minor
-    idx2 = flat_idx.reshape(rows, minor)
-    val2 = valid.reshape(rows, minor)
-    rb = seg.row_block if row_block is None else min(row_block, rows)
-    while rows % rb:
-        rb -= 1
-    grid = (rows // rb,)
-    fill = jnp.asarray([m.fill], dtype=x.dtype)
-    in_specs = [
-        pl.BlockSpec(x.shape, lambda i: (0,) * x.ndim),   # whole input slab
-        pl.BlockSpec((rb, minor), lambda i: (i, 0)),
-        pl.BlockSpec((rb, minor), lambda i: (i, 0)),
-        pl.BlockSpec((1,), lambda i: (0,)),
-    ]
-    args = [x, idx2, val2, fill]
-    if y is not None:
-        in_specs.append(pl.BlockSpec((rb, minor), lambda i: (i, 0)))
-        args.append(y.reshape(rows, minor))
-    out = pl.pallas_call(
-        _gather_kernel(ew),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rb, minor), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, minor), x.dtype),
-        interpret=interpret,
-    )(*args)
-    return out.reshape(m.out_shape)
+def gather_sig(m: MixedRadixMap, dtype, ew: str | None = None,
+               segment_bytes: int | None = None):
+    from repro.kernels.tm_affine.chain import ChainSig
+    return ChainSig(links=((m, ew),), dtype=str(jnp.dtype(dtype)),
+                    segment_bytes=segment_bytes)
 
 
 # ---------------------------------------------------------------------------
 # entry point
 # ---------------------------------------------------------------------------
 
-def tm_affine(x: jnp.ndarray, m: MixedRadixMap, *, interpret: bool = True,
+def tm_affine(x: jnp.ndarray, m: MixedRadixMap, *,
+              interpret: bool | None = None,
               block: tuple[int, ...] | None = None,
               force_mode: str | None = None,
-              y: jnp.ndarray | None = None, ew=None,
+              y: jnp.ndarray | None = None, ew: str | None = None,
               segment_bytes: int | None = None) -> jnp.ndarray:
     """Execute a MixedRadixMap as a Pallas kernel (decode -> block|gather).
 
     ``y``/``ew``: optional fused element-wise epilogue — ``ew(map(x), y)``
-    computed inside the kernel while the output block is VMEM-resident
-    (``y`` must have ``m.out_shape``).
+    (``ew`` an :data:`~repro.core.engine.EW_FNS` name) computed inside the
+    kernel while the output block is VMEM-resident (``y`` must have
+    ``m.out_shape``).
 
     ``segment_bytes``: custom ping-pong budget — resizes the block/gather
     grids exactly like :class:`~repro.core.schedule.CycleParams` resizes the
-    cycle model's segments (None = the shared default).
+    cycle model's segments (None = the shared default).  ``interpret``
+    None: decided by :func:`repro.platform.pallas_interpret`.
     """
+    from repro.kernels.tm_affine.chain import tm_chain
     assert x.shape == m.in_shape, (x.shape, m.in_shape)
     assert (y is None) == (ew is None)
     if y is not None:
         assert y.shape == m.out_shape, (y.shape, m.out_shape)
+    if interpret is None:
+        interpret = pallas_interpret(x)
     plan = (None if force_mode == "gather"
             else analyze_block_mode(m, block, segment_bytes))
-    if plan is not None and force_mode != "gather":
-        return _block_call(x, m, plan, interpret, y=y, ew=ew)
-    return _gather_call(x, m, interpret, y=y, ew=ew,
-                        segment_bytes=segment_bytes)
+    if plan is not None:
+        return _block_call(x, m, plan, interpret, y=y,
+                           ew=EW_FNS[ew] if ew is not None else None)
+    sig = gather_sig(m, x.dtype, ew, segment_bytes)
+    return tm_chain(sig, x, () if y is None else (y,), interpret=interpret)

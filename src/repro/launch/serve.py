@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.registry import get_config, get_smoke, list_archs
 from repro.models.transformer import init_caches, init_lm, init_states
 from repro.obs.tracer import as_tracer
+from repro.platform import enable_compile_cache
 from repro.runtime.step import make_decode_step, make_prefill_step
 
 
@@ -168,6 +169,7 @@ def main(argv=None):
                     help="record a span timeline and export Chrome-trace "
                          "JSON (open at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     tracer = as_tracer(bool(args.trace))
     if args.cnn:
         serve_cnn(n_requests=args.requests, max_batch=args.max_batch,

@@ -111,6 +111,10 @@ class CacheEntry:
     # the pinned compilation, memoized on first warm admission so the hot
     # path never re-walks the graph
     phase_cycle_pred: tuple | None = None
+    # phase index -> the report of its last served execution (a
+    # LoweringReport: which kernel path each instruction took, with the
+    # fallback reasons; or a TPUPhaseReport)
+    lowerings: dict = dataclasses.field(default_factory=dict)
 
 
 class CompileCache:
@@ -140,6 +144,11 @@ class CompileCache:
     def keys(self) -> list[CacheKey]:
         with self._lock:
             return list(self._entries)
+
+    def entries(self) -> list[CacheEntry]:
+        """The cached entries (no hit/miss accounting)."""
+        with self._lock:
+            return list(self._entries.values())
 
     @property
     def hit_rate(self) -> float:

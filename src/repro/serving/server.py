@@ -84,7 +84,6 @@ class ServerConfig:
 
     backend: str = "fused"          # requested backend (cache-key component)
     backend_candidates: tuple[str, ...] = ()  # non-empty: probe + pin winner
-    interpret: bool = True          # Pallas interpreter mode (CPU-safe)
     # bit-exact TPU phases: one XLA computation per eqn, literals baked —
     # matches eager dispatch granularity so served logits equal the
     # uncompiled model's bit for bit (the decode gate); costs the
@@ -217,6 +216,12 @@ def select_chain_fusion(part, launch_overhead_cycles: float = 32.0,
         "launches_unfused": part.launches(chained=False),
         "launches_chained": part.launches(chained=True),
     }
+
+
+def _probe_declines(reports) -> list[str]:
+    """The distinct up-front declines (``"rule: why"``) of a probe run —
+    why a modeled chain or crossing did not realize."""
+    return sorted({d for r in reports for d in getattr(r, "declines", ())})
 
 
 def predict_cycles(compiled: CompiledTMProgram,
@@ -772,12 +777,10 @@ class TMServer:
         tracer = self.tracer if traced else None
         backend = entry.degraded_phases.get(phase.index, entry.backend)
         try:
-            compiled.run_phase(phase, env, backend=backend,
-                               interpret=cfg.interpret,
-                               fuse_chains=(entry.fuse_chains
-                                            and backend == entry.backend),
-                               exact=cfg.exact, tracer=tracer,
-                               quarantine=entry.quarantine)
+            entry.lowerings[phase.index] = compiled.run_phase(
+                phase, env, backend=backend,
+                fuse_chains=(entry.fuse_chains and backend == entry.backend),
+                exact=cfg.exact, tracer=tracer, quarantine=entry.quarantine)
         except Exception as e:  # noqa: BLE001 — degradation ladder below
             if phase.kind != "tmu":
                 raise  # TPU phases have no alternative backend to fall to
@@ -788,11 +791,10 @@ class TMServer:
                 try:
                     # phase thunks are pure writes into env, so the retry
                     # simply overwrites whatever the failed attempt left
-                    compiled.run_phase(phase, env, backend=rung,
-                                       interpret=cfg.interpret,
-                                       fuse_chains=False, exact=cfg.exact,
-                                       tracer=tracer,
-                                       quarantine=entry.quarantine)
+                    entry.lowerings[phase.index] = compiled.run_phase(
+                        phase, env, backend=rung, fuse_chains=False,
+                        exact=cfg.exact, tracer=tracer,
+                        quarantine=entry.quarantine)
                 except Exception as e2:  # noqa: BLE001 — next rung
                     err = e2
                     continue
@@ -909,7 +911,7 @@ class TMServer:
         entry, _ = self.cache.get_or_compile(
             key, lambda: self._build_entry(key, members[0].fn, stacked))
         outs, _ = entry.compiled.run(
-            *stacked, backend=entry.backend, interpret=cfg.interpret,
+            *stacked, backend=entry.backend,
             fuse_chains=entry.fuse_chains, exact=cfg.exact,
             quarantine=entry.quarantine)
         return split(outs, len(members))
@@ -944,7 +946,7 @@ class TMServer:
                 t = time.perf_counter()
                 jax.block_until_ready(
                     compiled.run(*stacked_args, backend=cand,
-                                 interpret=cfg.interpret)[0])
+                                 exact=cfg.exact)[0])
                 walls[cand] = time.perf_counter() - t
             backend = min(walls, key=walls.get)
             selection["backend_probe_s"] = walls
@@ -958,9 +960,9 @@ class TMServer:
                 # chained execution and pin only what actually realizes, so
                 # the predicted overlap describes the shape that runs
                 _, reps = compiled.run(*stacked_args, backend="pallas",
-                                       interpret=cfg.interpret,
-                                       fuse_chains=True)
+                                       fuse_chains=True, exact=cfg.exact)
                 rows["realized_chains"] = sum(r.chain_count() for r in reps)
+                rows["declines"] = _probe_declines(reps)
                 fuse_chains = rows["realized_chains"] > 0
             selection["fuse_chains"] = {"winner": fuse_chains, **rows}
         cross_engine = False
@@ -989,12 +991,13 @@ class TMServer:
                                               compiled.params))
                     _, reps = candidate.run(
                         *stacked_args, backend="pallas",
-                        interpret=cfg.interpret, fuse_chains=fuse_chains,
+                        fuse_chains=fuse_chains, exact=cfg.exact,
                         quarantine=quarantine)
                     realized = sum(
                         1 for rep in reps for r in rep.records
                         if (r.path or "").startswith("pallas.xchain"))
                     rows["realized_crossings"] = realized
+                    rows["declines"] = _probe_declines(reps)
                     if realized:
                         compiled = candidate
                         cross_engine = True

@@ -341,7 +341,7 @@ def test_traced_dynamic_slice_does_not_trigger_pjit_inlining(rng):
     c = tm_compile(lambda a, i: inner(a, i) + 0.0, x, jnp.int32(1))
     assert "dynamic_slice" not in c.matched_prims
     kinds = [n.kind for n in c.graph.nodes]
-    # the pjit stayed one opaque node (+ the outer scalar add): no explosion
+    # the jit stayed one opaque node (+ the outer scalar add): no explosion
     assert kinds == ["tpu", "tpu"], kinds
     got = c(x, jnp.int32(1))
     assert np.array_equal(np.asarray(got),

@@ -99,7 +99,9 @@ def test_reconfigurability_new_op_without_new_datapath(rng):
                      inputs=("x",), outputs=("y",))
     got = TMExecutor()(prog, {"x": x})["y"]
     assert np.array_equal(np.asarray(got), np.asarray(x)[::-1, ::-1, :])
-    # and the generic Pallas kernel also executes it, block-mode
+    # and the generic Pallas kernel also executes it — as row copies: a
+    # reversal inside a (8, 128) tile has no Mosaic lowering, so the
+    # flipped sublane axis rules block mode out
     from repro.kernels.tm_affine import plan_of, tm_affine_call
     big = af.MixedRadixMap(
         out_shape=(64, 128, 8), in_shape=(64, 128, 8), splits=(),
@@ -108,4 +110,4 @@ def test_reconfigurability_new_op_without_new_datapath(rng):
     xb = jnp.asarray(rng.rand(64, 128, 8).astype(np.float32))
     got2 = tm_affine_call(xb, big, interpret=True)
     assert np.array_equal(np.asarray(got2), np.asarray(xb)[::-1, ::-1, :])
-    assert plan_of(big) is not None  # decoded to pure-DMA block mode
+    assert plan_of(big) is None  # decoded to gather mode

@@ -3,7 +3,7 @@
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.launch.mesh import rules_for_cell, specialize_rules
 from repro.runtime.sharding import (DEFAULT_RULES, shard, spec_of,
@@ -11,7 +11,8 @@ from repro.runtime.sharding import (DEFAULT_RULES, shard, spec_of,
 
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_spec_resolution_outside_context_is_noop():
