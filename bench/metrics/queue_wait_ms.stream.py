@@ -1,0 +1,3 @@
+"""``queue_wait_ms.stream``: mean submit to first phase start of the requests served in the traced window (ServerStats queue-delay series)."""
+
+from bench.readers import queue_wait_ms as read  # noqa: F401

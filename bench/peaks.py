@@ -1,0 +1,22 @@
+"""Published peaks per chip, keyed by ``jax.Device.device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.  A device
+kind missing from the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to bench/peaks.py with "
+                       f"their source") from None
