@@ -10,9 +10,8 @@ by CI, like the compiler trajectory):
   vs. cache-warm batched throughput (second pass);
 * **pipeline overlap** — mixed conv+TM traffic (``espcn``) through the
   two-engine pipeline: measured overlap ratio next to the cycle model's
-  prediction.  This pass runs traced, so the report also embeds the
-  :class:`~repro.obs.TraceReport` per-phase measured-vs-modeled table
-  (``--trace out.json`` additionally exports the Chrome-trace timeline).
+  prediction.  This pass runs traced (``--trace out.json`` exports its
+  Chrome-trace timeline).
 
 Acceptance gate: warm batched serving must clear 2x the uncached
 per-request throughput (the compile cache + micro-batching dividend).
@@ -32,7 +31,7 @@ import jax.numpy as jnp
 
 from repro.compiler import tm_compile
 from repro.models import cnn
-from repro.obs import Tracer, TraceReport
+from repro.obs import Tracer
 from repro.serving import ServerConfig, TMServer
 
 SHAPE = (1, 8, 12, 8)          # superres_tail request: x (B,H,W,C), s=2
@@ -106,10 +105,8 @@ def bench_server(rng, max_batch: int) -> dict:
 
 
 def bench_overlap(rng, tracer: Tracer) -> dict:
-    """Mixed conv+TM traffic: the two-engine pipeline's overlap ratio.
-
-    Runs traced so the per-phase wall time of the espcn program can be
-    joined against the cycle model's predictions (``trace_report``)."""
+    """Mixed conv+TM traffic: the two-engine pipeline's overlap ratio,
+    traced."""
     params = cnn.init_espcn(jax.random.PRNGKey(0), s=2)
 
     def espcn(img):
@@ -126,21 +123,11 @@ def bench_overlap(rng, tracer: Tracer) -> dict:
             for f in futs:
                 f.result(timeout=300)
         snap = srv.snapshot_stats()
-        # join measured per-phase wall time (trace) with the cycle model's
-        # per-phase prediction for the one cached espcn program
-        entry = srv.cache.get(srv.cache.keys()[0])
-        report = TraceReport.from_tracer(tracer, entry.compiled)
     return {
         "overlap_ratio": snap["overlap_ratio"],
         "predicted_overlap": snap["predicted_overlap"],
         "engine_busy_s": snap["engine_busy_s"],
         "pipeline_span_s": snap["pipeline_span_s"],
-        "trace_report": {
-            "rows": [r.as_dict() for r in report.rows],
-            "covered": report.covered(),
-            "table": report.table(),
-            "summary": report.summary(),
-        },
     }
 
 
@@ -182,8 +169,6 @@ def main(argv=None) -> dict:
     print(f"pipeline overlap: {overlap['overlap_ratio']:.1%} measured / "
           f"{overlap['predicted_overlap']:.1%} predicted (espcn)")
     print(f"warm-batched over uncached: {speedup:.1f}x")
-    print("\n# per-phase measured vs modeled (espcn, traced overlap pass)")
-    print(overlap["trace_report"]["summary"])
 
     with open("BENCH_serving.json", "w") as f:
         json.dump(report, f, indent=2)

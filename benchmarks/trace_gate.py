@@ -19,9 +19,8 @@ traced — and the traced run's timeline is checked against three gates:
   ``ServerStats.overlap_ratio()`` within ``MAX_OVERLAP_DELTA`` (0.02) —
   the trace and the stats must describe the same execution.
 
-Artifacts: ``BENCH_trace.json`` (gate numbers + the per-phase
-measured-vs-modeled table) and ``serving.trace.json`` (the Chrome-trace
-timeline; open at https://ui.perfetto.dev).
+Artifacts: ``BENCH_trace.json`` (gate numbers) and ``serving.trace.json``
+(the Chrome-trace timeline; open at https://ui.perfetto.dev).
 
     PYTHONPATH=src python benchmarks/trace_gate.py
 """
@@ -36,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import cnn
-from repro.obs import Tracer, TraceReport, overlap_from_trace
+from repro.obs import Tracer, overlap_from_trace
 from repro.serving import ServerConfig, TMServer
 
 SHAPE = (1, 40, 48, 3)          # request image: large enough that per-phase
@@ -119,7 +118,6 @@ def main() -> dict:
 
     # --- integrity + artifacts --------------------------------------------
     nesting = tracer.nesting_errors()
-    report_tbl = TraceReport.from_tracer(tracer, compiled)
     trace = tracer.export_chrome_trace(TRACE_PATH)
 
     report = {
@@ -139,11 +137,6 @@ def main() -> dict:
         "max_overlap_delta": MAX_OVERLAP_DELTA,
         "nesting_errors": nesting,
         "trace_events": len(trace["traceEvents"]),
-        "trace_report": {
-            "rows": [r.as_dict() for r in report_tbl.rows],
-            "covered": report_tbl.covered(),
-            "table": report_tbl.table(),
-        },
     }
 
     print("# trace_gate (espcn through TMServer, traced vs untraced)")
@@ -156,7 +149,6 @@ def main() -> dict:
           f"{trace_overlap['overlap_ratio']:.3f} trace "
           f"(delta {overlap_delta:.4f}, gate {MAX_OVERLAP_DELTA})")
     print(f"trace: {len(trace['traceEvents'])} events -> {TRACE_PATH}")
-    print("\n" + report_tbl.summary())
 
     with open("BENCH_trace.json", "w") as f:
         json.dump(report, f, indent=2)

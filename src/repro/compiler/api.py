@@ -174,7 +174,7 @@ class CompiledTMProgram:
     def _phase_hbm_bytes(self, phase: Phase) -> int:
         """Data-movement estimate of one phase execution: every external
         read plus every downstream-visible write through HBM once.
-        Memoized per phase — it sits on the traced hot path."""
+        Memoized per phase."""
         cache = self.__dict__.setdefault("_hbm_bytes_cache", {})
         total = cache.get(phase.index)
         if total is None:
@@ -217,11 +217,10 @@ class CompiledTMProgram:
         ``tracer`` (a :class:`repro.obs.Tracer`) wraps the execution in a
         ``phase/{index}/{kind}`` span; at ``Tracer(detail="instr")`` the
         span also carries the phase's launch/segment accounting and the
-        ``tmu/launches``, ``tmu/segments``, ``tpu/xla_computations`` and
-        ``hbm/bytes`` counters accumulate (evaluating that payload per
-        phase is NOT free, which is why the default "phase" detail records
-        the bare interval); the default no-op tracer costs one attribute
-        check.
+        ``tmu/launches``, ``tmu/segments`` and ``tpu/xla_computations``
+        counters accumulate (evaluating that payload per phase is NOT free,
+        which is why the default "phase" detail records the bare interval);
+        the default no-op tracer costs one attribute check.
 
         ``quarantine`` (the owning cache entry's mutable set) arms the
         kernel degradation ladder on the pallas backend — see
@@ -252,7 +251,6 @@ class CompiledTMProgram:
                            segments=segments, chains=rep.chain_count())
                     tracer.count("tmu/launches", launches)
                     tracer.count("tmu/segments", segments)
-                tracer.count("hbm/bytes", self._phase_hbm_bytes(phase))
         return rep
 
     def _exec_phase(self, phase: Phase, env: dict[str, Any], *,

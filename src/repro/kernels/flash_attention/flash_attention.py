@@ -93,6 +93,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        name="flash_attention",
         interpret=(pallas_interpret(q) if interpret is None
                    else interpret),
     )(q, k, v)
@@ -160,6 +161,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
+        name="flash_attention",
         interpret=(pallas_interpret(q) if interpret is None
                    else interpret),
     )(q, k, v, lens)

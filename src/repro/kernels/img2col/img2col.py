@@ -76,6 +76,7 @@ def img2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1, pad: int = 0,
         )],
         out_specs=pl.BlockSpec((oh_b * OW, kh * kw * C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, kh * kw * C), x.dtype),
+        name="img2col",
         interpret=(pallas_interpret(x) if interpret is None
                    else interpret),
     )(xp) if slab_rows == oh_b * stride else _img2col_overlap(
@@ -98,6 +99,7 @@ def _img2col_overlap(xp, OH, OW, kh, kw, C, stride, oh_b, interpret):
         in_specs=[pl.BlockSpec(xp.shape, lambda i: (0, 0, 0))],
         out_specs=pl.BlockSpec((oh_b * OW, kh * kw * C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, kh * kw * C), xp.dtype),
+        name="img2col",
         interpret=(pallas_interpret(xp) if interpret is None
                    else interpret),
     )(xp)
@@ -137,6 +139,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, pad: int = 0,
         ],
         out_specs=pl.BlockSpec((oh_b * OW, OC), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((OH * OW, OC), x.dtype),
+        name="img2col",
         interpret=(pallas_interpret(x) if interpret is None
                    else interpret),
     )(xp, wm)

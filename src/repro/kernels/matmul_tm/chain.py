@@ -112,7 +112,8 @@ def _commit_executable(sig, eqn, op_sds: tuple, interpret: bool):
     if hit is None:
         plan = build_chain_plan(sig)
         head = rw.Head(fn=_stage_fn(eqn, interpret), n_ops=len(op_sds))
-        call = _with_tables(rw.build_call(plan.program, interpret, head=head),
+        call = _with_tables(rw.build_call(plan.program, interpret, head=head,
+                                          name="xchain"),
                             rw.address_tables(plan.program, head=True))
         hit = _EXECUTABLES[key] = (call, plan, plan.n_segments)
     return hit
@@ -137,7 +138,8 @@ def _prologue_executable(sig, eqn, op_sds: tuple, cross_pos: int,
         sink = rw.Sink(fn=compute, n_ops=len(op_sds) - 1,
                        out_shape=tuple(out_aval.shape),
                        out_dtype=out_aval.dtype)
-        call = _with_tables(rw.build_call(plan.program, interpret, sink=sink),
+        call = _with_tables(rw.build_call(plan.program, interpret, sink=sink,
+                                          name="xchain"),
                             rw.address_tables(plan.program))
         hit = _EXECUTABLES[key] = (call, plan, plan.n_segments)
     return hit

@@ -94,6 +94,7 @@ def matmul_tm(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec(out_block, out_index_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="matmul_tm",
         interpret=(pallas_interpret(x) if interpret is None
                    else interpret),
     )(x, w)

@@ -65,6 +65,7 @@ def resize_bilinear(x: jnp.ndarray, out_h: int, out_w: int, *,
         ],
         out_specs=pl.BlockSpec((rb, out_w, C), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((out_h, out_w, C), x.dtype),
+        name="resize",
         interpret=(pallas_interpret(x) if interpret is None
                    else interpret),
     )(x, y0, y1, wy, x0, x1, wx)

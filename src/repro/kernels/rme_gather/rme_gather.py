@@ -72,6 +72,7 @@ def _compact(x, pred_args, keep, capacity: int, interpret):
         out_shape=[jax.ShapeDtypeStruct((B * capacity, 1, D), x.dtype),
                    jax.ShapeDtypeStruct((B, 1, capacity), jnp.int32),
                    jax.ShapeDtypeStruct((B, 1, 1), jnp.int32)],
+        name="rme_gather",
         interpret=(pallas_interpret(x) if interpret is None
                    else interpret),
     )(*pred_args, x.reshape(B * N, 1, D))

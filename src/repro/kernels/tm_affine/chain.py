@@ -296,8 +296,10 @@ def tpu_decline(sig: ChainSig) -> str | None:
 
 def tm_chain(sig: ChainSig, x: jnp.ndarray,
              slabs: tuple[jnp.ndarray, ...] = (), *,
-             interpret: bool) -> jnp.ndarray:
+             interpret: bool, name: str = "tm_chain") -> jnp.ndarray:
     """Execute a chain signature: ``x`` is the chain source, ``slabs`` the
-    epilogue operands then non-chain Route band sources, in link order."""
+    epilogue operands then non-chain Route band sources, in link order.
+    ``name`` is the launched kernel's family: ``tm_chain`` for a forwarding
+    chain; gather mode and overlay routes pass their own."""
     prog = build_chain_plan(sig).program
-    return rw.run(prog, (x,) + tuple(slabs), interpret=interpret)
+    return rw.run(prog, (x,) + tuple(slabs), interpret=interpret, name=name)

@@ -163,7 +163,8 @@ def _overlay_matches(ins, srcs, batch_dims, segment_bytes=None):
 
 def _overlay_run(ins, srcs, batch_dims, interpret, segment_bytes=None):
     sig = _overlay_sig(ins, srcs, batch_dims, segment_bytes)
-    return tm_chain(sig, srcs[0], tuple(srcs[1:]), interpret=interpret)
+    return tm_chain(sig, srcs[0], tuple(srcs[1:]), interpret=interpret,
+                    name="tm_overlay")
 
 
 def _overlay_segments(ins, srcs, batch_dims, segment_bytes=None):

@@ -415,11 +415,14 @@ def address_tables(prog: RowProgram, head: bool = False) -> tuple:
 
 
 def build_call(prog: RowProgram, interpret: bool, *, head: Head | None = None,
-               sink: Sink | None = None):
+               sink: Sink | None = None, name: str):
     """``call(tables, *head operands, *slabs, *sink operands) -> out`` for
     ``prog`` (jit-able; ``tables`` is :func:`address_tables` of the
     program).  With
-    a ``head``, gather 0 reads the head's VMEM result and takes no slab."""
+    a ``head``, gather 0 reads the head's VMEM result and takes no slab.
+    ``name`` is the kernel's family, given by the caller (gather mode,
+    chains, overlay routes and the cross-engine kernels all launch here),
+    so a device trace tells them apart."""
     G, rb, L = prog.grid, prog.rb, prog.L
     dtype = jnp.dtype(prog.dtype)
     Ws = address_tables(prog, head is not None)[3]
@@ -481,18 +484,20 @@ def build_call(prog: RowProgram, interpret: bool, *, head: Head | None = None,
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
+            name=name,
         )(lo, *args)
         return out if sink else out.reshape(prog.out_shape)
 
     return call
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _run(prog: RowProgram, interpret: bool, tabs, *slabs):
-    return build_call(prog, interpret)(tabs, *slabs)
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _run(prog: RowProgram, interpret: bool, name: str, tabs, *slabs):
+    return build_call(prog, interpret, name=name)(tabs, *slabs)
 
 
-def run(prog: RowProgram, slabs, *, interpret: bool):
-    """Execute ``prog`` on its runtime operands (one jit per program)."""
+def run(prog: RowProgram, slabs, *, interpret: bool, name: str):
+    """Execute ``prog`` on its runtime operands (one jit per program) as a
+    kernel of family ``name``."""
     tabs = address_tables(prog)
-    return _run(prog, interpret, tabs[:3] + (None,), *slabs)
+    return _run(prog, interpret, name, tabs[:3] + (None,), *slabs)

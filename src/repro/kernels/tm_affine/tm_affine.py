@@ -269,6 +269,7 @@ def _block_call(x: jnp.ndarray, m: MixedRadixMap, plan: BlockPlan,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(plan.block, lambda *g: g),
         out_shape=jax.ShapeDtypeStruct(m.out_shape, x.dtype),
+        name="tm_affine_block",
         interpret=interpret,
     )(*args)
 
@@ -319,4 +320,5 @@ def tm_affine(x: jnp.ndarray, m: MixedRadixMap, *,
         return _block_call(x, m, plan, interpret, y=y,
                            ew=EW_FNS[ew] if ew is not None else None)
     sig = gather_sig(m, x.dtype, ew, segment_bytes)
-    return tm_chain(sig, x, () if y is None else (y,), interpret=interpret)
+    return tm_chain(sig, x, () if y is None else (y,), interpret=interpret,
+                    name="tm_affine_rows")

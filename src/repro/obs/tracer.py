@@ -15,9 +15,14 @@ One :class:`Tracer` is the single timeline of a compile/execute/serve run:
 Everything records ``time.monotonic()`` seconds — the same clock the stream
 runtime stamps events with — and is appended under one lock whose critical
 section is a single ``list.append``; the recorded payload is built outside
-it.  When tracing is off, the module-level :data:`NULL_TRACER` stands in:
-every method is a no-op and ``enabled`` is ``False``, so hot paths guard
-per-instruction recording with one attribute check.
+it.  Process hooks (:mod:`repro.obs.hooks`) record through
+:meth:`Tracer.post_span` instead, which takes no lock: a garbage collection
+can start inside any critical section, on the thread that holds the lock.
+:func:`clock_anchor` ties that clock to a ``jax.profiler`` trace's, so
+every span maps onto the device timeline.  When tracing is off, the
+module-level :data:`NULL_TRACER` stands in: every method is a no-op and
+``enabled`` is ``False``, so hot paths guard per-instruction recording with
+one attribute check.
 
 ``export_chrome_trace(path)`` writes Chrome-trace JSON (the ``traceEvents``
 array format): open it at https://ui.perfetto.dev or ``chrome://tracing``.
@@ -28,13 +33,33 @@ Tracks (``tid``) are one per engine/stream/thread, named via ``M``
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import threading
 import time
 from typing import Any, Callable
 
-__all__ = ["SpanRecord", "Tracer", "NullTracer", "NULL_TRACER", "as_tracer"]
+__all__ = ["SpanRecord", "Tracer", "NullTracer", "NULL_TRACER", "as_tracer",
+           "clock_anchor", "CLOCK_ANCHOR"]
+
+CLOCK_ANCHOR = "obs/clock"
+
+
+def clock_anchor() -> int:
+    """Mark the tracer's clock in a running ``jax.profiler`` trace.
+
+    Opens a ``TraceAnnotation`` named :data:`CLOCK_ANCHOR` and stores the
+    ``time.monotonic_ns()`` taken inside it as the event's
+    ``monotonic_ns`` stat.  A reader maps any span onto the profile's
+    clock with ``offset_ns = event start_ns - monotonic_ns``; two anchors,
+    one at each end of a trace, give the drift between the clocks.
+    Returns the stamp (a no-op marker when no profile is running)."""
+    import jax
+    with jax.profiler.TraceAnnotation(CLOCK_ANCHOR) as ann:
+        t = time.monotonic_ns()
+        ann.set_metadata(monotonic_ns=t)
+    return t
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,6 +156,10 @@ class Tracer:
         # dataclass on record costs ~5x the append, so the hot path stores
         # tuples and ``spans()`` materializes records lazily
         self._spans: list[tuple] = []
+        # spans posted by process hooks without the lock; readers move
+        # them into _spans under it (deque.append is atomic)
+        self._posted: collections.deque = collections.deque()
+        self.host_hooks = None   # repro.obs.hooks.HostHooks.of(self)
         self._instants: list[tuple] = []        # (name, track, t, args)
         self._counter_events: list[tuple] = []  # (name, track, t, value)
         self._counters: dict[str, float] = {}   # cumulative totals
@@ -147,6 +176,12 @@ class Tracer:
         with self._lock:
             self._spans.append(rec)
 
+    def _drain(self) -> None:
+        """Move posted spans into ``_spans``; the caller holds the lock."""
+        posted = self._posted
+        while posted:
+            self._spans.append(posted.popleft())
+
     def span(self, name: str, track: str | None = None, **args) -> _Span:
         """Open a nested span on this thread (``track=None`` inherits the
         enclosing span's track, else the thread's name)."""
@@ -161,6 +196,28 @@ class Tracer:
         check and export as Chrome async events."""
         self._record((name, track, t_start, t_end, 0,
                       tuple(args.items()), overlap_ok))
+
+    def post_span(self, name: str, track: str, t_start: float,
+                  t_end: float, overlap_ok: bool = False, **args) -> None:
+        """:meth:`add_span` without the lock, for hooks that can run on a
+        thread which holds it (a ``gc.callbacks`` hook fires wherever a
+        collection starts).  The span joins the others at the next read."""
+        self._posted.append((name, track, t_start, t_end, 0,
+                             tuple(args.items()), overlap_ok))
+
+    def set_running(self, label: str | None) -> None:
+        """Mark the task this thread runs (a stream worker's phase, whose
+        span is recorded only after it ends); ``None`` clears it."""
+        self._tls.running = label
+
+    def current(self) -> str | None:
+        """The innermost span open on this thread, else the task marked
+        by :meth:`set_running` — what a compile or collection seen on this
+        thread happened inside."""
+        stack = self._stack()
+        if stack:
+            return stack[-1].name
+        return getattr(self._tls, "running", None)
 
     def instant(self, name: str, track: str | None = None, **args) -> None:
         t = self._clock()
@@ -191,6 +248,7 @@ class Tracer:
     def spans(self, prefix: str | None = None,
               track: str | None = None) -> list[SpanRecord]:
         with self._lock:
+            self._drain()
             raw = list(self._spans)
         if prefix is not None:
             raw = [t for t in raw if t[0].startswith(prefix)]
@@ -205,6 +263,7 @@ class Tracer:
 
     def tracks(self) -> list[str]:
         with self._lock:
+            self._drain()
             seen: dict[str, None] = {}
             for t in self._spans:
                 seen.setdefault(t[1])
@@ -251,6 +310,7 @@ class Tracer:
     def chrome_trace(self) -> dict:
         """The trace as a Chrome-trace dict (``{"traceEvents": [...]}``)."""
         with self._lock:
+            self._drain()
             spans = list(self._spans)
             instants = list(self._instants)
             counter_events = list(self._counter_events)
@@ -331,6 +391,14 @@ class NullTracer:
     def add_span(self, name: str, track: str, t_start: float, t_end: float,
                  overlap_ok: bool = False, **args) -> None:
         pass
+
+    post_span = add_span
+
+    def set_running(self, label: str | None) -> None:
+        pass
+
+    def current(self) -> str | None:
+        return None
 
     def instant(self, name: str, track: str | None = None, **args) -> None:
         pass

@@ -95,6 +95,9 @@ class StreamEvent:
     # watchdog deadline: once RUNNING for longer than this, PhaseWatchdog
     # poisons the event with PhaseTimeoutError (None = never)
     timeout_s: float | None = None
+    # ((key, value), ...) added to the event's trace span (the admitted
+    # group's id); empty when tracing is off
+    args: tuple = ()
 
     def __post_init__(self):
         self._done = threading.Event()
@@ -199,9 +202,11 @@ class Stream:
     def submit(self, fn: Callable[[], Any],
                deps: Sequence[StreamEvent] = (),
                label: str = "", front: bool = False,
-               timeout_s: float | None = None) -> StreamEvent:
+               timeout_s: float | None = None,
+               args: tuple = ()) -> StreamEvent:
         event = StreamEvent(engine=self.engine, label=label,
-                            t_submit=time.monotonic(), timeout_s=timeout_s)
+                            t_submit=time.monotonic(), timeout_s=timeout_s,
+                            args=args)
         event._owner = self
         task = _Task(fn=fn, deps=tuple(deps), event=event)
         with self._cond:
@@ -301,7 +306,8 @@ class Stream:
             self._cond.notify_all()
         if self.tracer is not None and self.tracer.enabled:
             self.tracer.add_span(event.label or "task", self.engine,
-                                 event.t_start, event.t_end, ok=False)
+                                 event.t_start, event.t_end, ok=False,
+                                 **dict(event.args))
         event._complete()
         if self.observer is not None:
             try:
@@ -367,16 +373,26 @@ class Stream:
             if dep.error is not None and event.error is None:
                 event.error = dep.error   # up the ORIGINAL failure
         if event.error is None:
+            tracer = self.tracer
+            traced = tracer is not None and tracer.enabled
             with self._cond:
                 self._running = task
                 event.t_start = time.monotonic()
             result: Any = None
             err: BaseException | None = None
+            issued = None
+            if traced:
+                # a compile or collection on this thread names the phase
+                tracer.set_running(event.label)
             try:
                 hook = fault_hook
                 if hook is not None:
                     hook("stream", f"{self.engine}:{event.label}")
                 result = task.fn()
+                if traced:
+                    # the host has issued the task's work: what follows is
+                    # the wait for the device
+                    issued = time.monotonic()
                 # resolve async dispatch on OUR thread so t_end is the work's
                 # completion (a device-event timestamp), not its enqueue; the
                 # other stream and the host keep running meanwhile
@@ -384,6 +400,8 @@ class Stream:
             except BaseException as e:  # noqa: BLE001 — delivered via event
                 err = e
             t_end = time.monotonic()
+            if traced:
+                tracer.set_running(None)
             with self._cond:
                 if gen != self._gen or event.done:
                     # poison_running fired while fn was stuck: the event
@@ -395,14 +413,18 @@ class Stream:
                 event.result = result
                 event.error = err
                 event.t_end = t_end
-            if self.tracer is not None and self.tracer.enabled:
+            if traced:
                 # the realized busy interval, on the ENGINE's track — the
                 # exact timestamps the serving stats ingest, so the trace
-                # and the overlap accounting share one source of truth
-                self.tracer.add_span(
+                # and the overlap accounting share one source of truth.
+                # ``issued`` splits it: host issue, then device wait (a
+                # task that raised spent it all on the host)
+                tracer.add_span(
                     event.label or "task", self.engine,
                     event.t_start, event.t_end,
-                    ok=event.error is None)
+                    ok=event.error is None,
+                    issued=t_end if issued is None else issued,
+                    **dict(event.args))
         event._complete()
         if self.observer is not None:
             try:
@@ -470,12 +492,14 @@ class StreamRuntime:
     def submit(self, engine: str, fn: Callable[[], Any],
                deps: Sequence[StreamEvent] = (),
                label: str = "", front: bool = False,
-               timeout_s: float | None = None) -> StreamEvent:
+               timeout_s: float | None = None,
+               args: tuple = ()) -> StreamEvent:
         if engine not in self.streams:
             raise ValueError(f"unknown engine {engine!r}; expected one of "
                              f"{tuple(self.streams)}")
         return self.streams[engine].submit(fn, deps=deps, label=label,
-                                           front=front, timeout_s=timeout_s)
+                                           front=front, timeout_s=timeout_s,
+                                           args=args)
 
     def try_cancel(self, event: StreamEvent) -> bool:
         """Cancel a not-yet-issued task on whichever stream holds it (see

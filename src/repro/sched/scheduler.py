@@ -134,6 +134,7 @@ class _JobRun:
         how many were *re*-submissions of previously cancelled phases."""
         resubmitted = 0
         timeouts = getattr(self.prep, "step_timeouts", None)
+        span_args = getattr(self.prep, "span_args", ())
         with self.lock:
             self.state = "running"
             for i, (kind, thunk) in enumerate(self.prep.steps):
@@ -153,7 +154,8 @@ class _JobRun:
                 new_ev = self.sched.runtime.submit(
                     kind, thunk, deps=deps, label=label, front=front,
                     timeout_s=(timeouts[i] if timeouts is not None
-                               else None))
+                               else None),
+                    args=span_args)
                 self.events[i] = new_ev
                 new_ev.add_done_callback(
                     functools.partial(self._phase_done, i, new_ev))

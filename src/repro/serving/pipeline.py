@@ -50,7 +50,8 @@ class PipelineJob:
     exception.  ``step_labels`` overrides the per-step event label
     (default ``{label}#{i}:{kind}``) — the server uses it to name stream
     events ``phase/{index}/{kind}`` so the engine-lane trace spans double
-    as the phase spans."""
+    as the phase spans.  ``span_args`` (``((key, value), ...)``) ride on
+    every step's trace span: the server's group id."""
 
     steps: list[tuple[str, Callable[[], object]]]
     on_done: Callable[[BaseException | None], None]
@@ -60,6 +61,7 @@ class PipelineJob:
     # per-step watchdog deadlines (seconds; None = unbounded) — forwarded to
     # StreamEvent.timeout_s so PhaseWatchdog can poison a hung step
     step_timeouts: Sequence[float | None] | None = None
+    span_args: tuple = ()
 
     def __post_init__(self):
         for kind, _ in self.steps:
@@ -188,7 +190,8 @@ class RequestPipeline:
                 kind, thunk, deps=[events[d] for d in dep_idx],
                 label=label,
                 timeout_s=(job.step_timeouts[i]
-                           if job.step_timeouts is not None else None)))
+                           if job.step_timeouts is not None else None),
+                args=job.span_args))
 
         # completion accounting: every event either completes (its callback
         # decrements) or is error-aborted below before it ever issued (the

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import logging
 import threading
 import time
@@ -50,6 +51,7 @@ from repro.compiler.api import CompiledTMProgram, tm_compile
 from repro.compiler.partition import partition
 from repro.core.executor import BACKENDS
 from repro.core.schedule import CycleParams
+from repro.obs.hooks import HostHooks
 from repro.obs.tracer import as_tracer
 from repro.serving.batcher import (BucketQueue, Request, bucket_size,
                                    coalesce, split)
@@ -110,7 +112,10 @@ class ServerConfig:
     # observability: None/False = off (the no-op tracer — one attribute
     # check on the hot path), True = the server creates a repro.obs.Tracer
     # (exposed as ``TMServer.tracer``), or pass a Tracer to share one
-    # timeline across servers/sessions
+    # timeline across servers/sessions.  A traced server also records its
+    # process's compiles and garbage collections (repro.obs.hooks, once
+    # per tracer however many servers share it) while it runs, and tags
+    # every phase and request span with its group id
     trace: Any = None
     # admission scheduler: "continuous" (repro.sched — rolling group
     # formation at dispatch time, priority/deadline ordering, phase-boundary
@@ -327,6 +332,13 @@ class _AdmittedBatch:
     # per-phase watchdog deadlines (seconds; None = unbounded) — set only
     # for WARM executions when the watchdog is enabled
     step_timeouts: list | None = None
+    # admission sequence id, carried as arg ``group`` on the group's phase
+    # and request spans; None when tracing is off
+    group: int | None = None
+
+    @property
+    def span_args(self) -> tuple:
+        return () if self.group is None else (("group", self.group),)
 
 
 class TMServer:
@@ -344,6 +356,9 @@ class TMServer:
     def __init__(self, config: ServerConfig | None = None):
         self.config = config or ServerConfig()
         self.tracer = as_tracer(self.config.trace)
+        self._hooks = (HostHooks.of(self.tracer) if self.tracer.enabled
+                       else None)
+        self._groups = itertools.count()
         self.stats = ServerStats()
         self.cache = CompileCache(capacity=self.config.cache_capacity)
         self._queue = BucketQueue()
@@ -385,6 +400,8 @@ class TMServer:
             return self
         self._started = True
         self._stopping = False
+        if self._hooks is not None:
+            self._hooks.install()
         self._admit_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=2, thread_name_prefix="tm-serve-admit")
         self._retry_pool = concurrent.futures.ThreadPoolExecutor(
@@ -431,6 +448,8 @@ class TMServer:
         # groups handed off before the drain still resolve their futures
         self._retry_pool.shutdown(wait=True)
         self._retry_pool = None
+        if self._hooks is not None:
+            self._hooks.uninstall()
         self._started = False
 
     def __enter__(self) -> "TMServer":
@@ -621,7 +640,8 @@ class TMServer:
                 steps=prep.steps, deps=prep.deps,
                 on_done=lambda err: self._finalize(prep, err),
                 label=prep.label, step_labels=prep.step_labels,
-                step_timeouts=prep.step_timeouts))
+                step_timeouts=prep.step_timeouts,
+                span_args=prep.span_args))
         except BaseException as e:  # noqa: BLE001 — shutdown race
             self._fail_batch(prep.batch, e, cold=not prep.hit)
 
@@ -736,7 +756,9 @@ class TMServer:
                               entry=entry, env=env, phases=phases,
                               steps=steps, deps=deps, step_labels=step_labels,
                               label=f"{batch[0].fn_key}x{size}",
-                              step_timeouts=step_timeouts)
+                              step_timeouts=step_timeouts,
+                              group=(next(self._groups) if detail is not None
+                                     else None))
 
     def _finalize(self, prep: _AdmittedBatch,
                   err: BaseException | None) -> None:
@@ -766,7 +788,7 @@ class TMServer:
                 self.tracer.add_span(
                     f"request/{r.fn_key}", "requests",
                     r.t_submit, t_end, overlap_ok=True,
-                    cold=not hit, ok=True)
+                    cold=not hit, ok=True, group=prep.group)
         self._release(prep.n)
 
     def _run_phase(self, compiled: CompiledTMProgram, phase, env: dict,

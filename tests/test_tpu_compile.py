@@ -6,7 +6,9 @@ chip of a ``v5e:2x2`` topology that is described, not attached.  What the
 chip's compiler would refuse, these tests refuse — without a chip.  The
 topology is described inside a module fixture (never at import), so every
 test worker collects the same tests and only the one given this file loads
-the TPU library.
+the TPU library.  Every kernel carries its family's name (``pallas_call``'s
+``name=``), which the compiled HLO gives the kernel's instruction, so a
+device trace groups kernels by family.
 """
 
 import os
@@ -40,13 +42,28 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(sharding, fn, *shapes):
+def _compile(sharding, fn, *shapes, names):
     """Compile ``fn`` for the described chip; the HLO must hold a Mosaic
-    kernel (an interpret-mode lowering would hold none)."""
+    kernel (an interpret-mode lowering would hold none), and each of
+    ``names`` — the kernels' families — must name a kernel in the lowered
+    text and a custom-call instruction in the compiled HLO."""
     sds = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    text = jax.jit(fn).lower(*sds).compile().as_text()
+    lowered = jax.jit(fn).lower(*sds)
+    low_text = lowered.as_text()
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    for name in names:
+        assert f'kernel_name = "{name}"' in low_text, name
+        assert f"%{name}." in text, name
     return text
+
+
+def _family(m, segment_bytes=None) -> str:
+    """The kernel family ``tm_affine`` launches for ``m``."""
+    from repro.kernels.tm_affine.tm_affine import analyze_block_mode
+    return ("tm_affine_block"
+            if analyze_block_mode(m, None, segment_bytes) is not None
+            else "tm_affine_rows")
 
 
 def _tm_affine(m, segment_bytes=None):
@@ -77,7 +94,8 @@ def test_tm_affine_block_mode_compiles(one_chip, m, dtype):
         if analyze_block_mode(m, None, sb) is None and \
                 ch.tpu_decline(gather_sig(m, dtype, None, sb)) is not None:
             continue  # declined up front: the engine takes it
-        _compile(one_chip, _tm_affine(m, sb), (m.in_shape, dtype))
+        _compile(one_chip, _tm_affine(m, sb), (m.in_shape, dtype),
+                 names=[_family(m, sb)])
 
 
 # --- tm_affine gather mode: row gathers ------------------------------------
@@ -109,17 +127,20 @@ def test_tm_affine_gather_mode_compiles(one_chip, m, dtype):
                                                    gather_sig)
     assert analyze_block_mode(m) is None
     assert ch.tpu_decline(gather_sig(m, dtype)) is None
-    _compile(one_chip, _tm_affine(m), (m.in_shape, dtype))
+    _compile(one_chip, _tm_affine(m), (m.in_shape, dtype),
+             names=["tm_affine_rows"])
 
 
 def test_upsample_compiles(one_chip):
     m = batch_extend_map(af.upsample_map((14, 14, 128), 2), (B, 1))
-    _compile(one_chip, _tm_affine(m), (m.in_shape, jnp.float32))
+    _compile(one_chip, _tm_affine(m), (m.in_shape, jnp.float32),
+             names=[_family(m)])
 
 
 def test_rearrange_compiles(one_chip):
     m = batch_extend_map(af.rearrange_map((448, 448, 3), 1, 16), (B, 1))
-    _compile(one_chip, _tm_affine(m), (m.in_shape, jnp.float32))
+    _compile(one_chip, _tm_affine(m), (m.in_shape, jnp.float32),
+             names=[_family(m)])
 
 
 def test_route_bands_compile(one_chip):
@@ -132,17 +153,22 @@ def test_route_bands_compile(one_chip):
         return (tm_affine(u, maps[0], interpret=False)
                 + tm_affine(skip, maps[1], interpret=False))
     shape = (B, 1, 28, 28, 128)
-    _compile(one_chip, route, (shape, jnp.float32), (shape, jnp.float32))
+    _compile(one_chip, route, (shape, jnp.float32), (shape, jnp.float32),
+             names=sorted({_family(m) for m in maps}))
 
 
 def test_kv_append_overlay_compiles(one_chip):
-    """decode: the KV-cache append as one overlay launch."""
-    from repro.kernels.tm_affine.chain import ChainSig, tm_chain
+    """decode: the KV-cache append as one overlay launch, through the
+    overlay rule's own run."""
+    from repro.core.instr import TMInstr, TMOpcode
+    from repro.kernels.tm_affine.ops import _overlay_run
     cache, upd = (B, 256, 8, 128), (B, 1, 8, 128)
     maps = tuple(af.update_slice_maps(cache, upd, (0, 128, 0, 0)))
-    sig = ChainSig(links=(), route_maps=maps, dtype="bfloat16", overlay=True)
-    _compile(one_chip, lambda c, u: tm_chain(sig, c, (u,), interpret=False),
-             (cache, jnp.bfloat16), (upd, jnp.bfloat16))
+    ins = TMInstr(TMOpcode.COARSE, ("c", "u"), "out", maps=maps,
+                  meta={"overlay": True})
+    _compile(one_chip, lambda c, u: _overlay_run(ins, [c, u], 0, False),
+             (cache, jnp.bfloat16), (upd, jnp.bfloat16),
+             names=["tm_overlay"])
 
 
 # --- chains: one launch for a forwarding chain ------------------------------
@@ -187,7 +213,8 @@ def test_chain_compiles(one_chip, name):
     assert ch.tpu_decline(sig) is None
     _compile(one_chip,
              lambda x, *s: ch.tm_chain(sig, x, s, interpret=False),
-             (x, jnp.float32), *[(s, jnp.float32) for s in slabs])
+             (x, jnp.float32), *[(s, jnp.float32) for s in slabs],
+             names=["tm_chain"])
 
 
 # --- RME evaluate: Bboxcal over the detect tail's records --------------------
@@ -197,7 +224,7 @@ def test_rme_evaluate_compiles(one_chip):
     _compile(one_chip,
              lambda x: evaluate_batched(x, 0.5, 64, score_index=4,
                                         interpret=False),
-             ((B, 2352, 85), jnp.float32))
+             ((B, 2352, 85), jnp.float32), names=["rme_gather"])
 
 
 # --- cross-engine: a dot streamed through a TM chain -------------------------
@@ -238,7 +265,34 @@ def test_matmul_xchain_compiles(one_chip, direction):
         fn, _, _ = xc._prologue_executable(sig, eqn, op_sds, 0, False)
         shapes = [((1024, 8, 128), jnp.bfloat16),
                   ((1024, 3072), jnp.bfloat16)]
-    _compile(one_chip, fn, *shapes)
+    _compile(one_chip, fn, *shapes, names=["xchain"])
+
+
+# --- kernels no served rule launches still compile under their names ---------
+
+def test_matmul_tm_compiles(one_chip):
+    """The plain tiled matmul at phi4-mini's q|k|v projection widths."""
+    from repro.kernels.matmul_tm.matmul_tm import matmul_tm
+    _compile(one_chip, lambda x, w: matmul_tm(x, w, interpret=False),
+             ((B * 128, 3072), jnp.bfloat16), ((3072, 5120), jnp.bfloat16),
+             names=["matmul_tm"])
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_flash_attention_compiles(one_chip, step):
+    """Flash attention over phi4-mini's 24 heads of 128."""
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention, flash_decode)
+    kv = ((B * 24, 256, 128), jnp.bfloat16)
+    if step == "prefill":
+        _compile(one_chip,
+                 lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                 kv, kv, kv, names=["flash_attention"])
+    else:
+        _compile(one_chip,
+                 lambda q, k, v: flash_decode(q, k, v, 200, interpret=False),
+                 ((B * 24, 1, 128), jnp.bfloat16), kv, kv,
+                 names=["flash_attention"])
 
 
 # --- kernels that cannot compile decline up front on a TPU ------------------
