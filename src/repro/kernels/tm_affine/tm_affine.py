@@ -235,9 +235,21 @@ def _block_kernel(plan: BlockPlan, ew=None):
     return kernel
 
 
-def _block_call(x: jnp.ndarray, m: MixedRadixMap, plan: BlockPlan,
-                interpret: bool, y: jnp.ndarray | None = None,
-                ew=None) -> jnp.ndarray:
+@lru_cache(maxsize=256)
+def _block_launcher(plan: BlockPlan, in_shape: tuple[int, ...],
+                    out_shape: tuple[int, ...], dtype, ew: str | None,
+                    interpret: bool):
+    """The jitted block-mode launch of one static signature: built, traced
+    and compiled on its first call, then reused.
+
+    ``pl.pallas_call`` returns a fresh jitted function each time it is
+    built, so building it per call recompiles the kernel on every eager
+    launch.  The key is what the compiled kernel depends on: the plan, the
+    map's shapes, the dtype and the epilogue by its
+    :data:`~repro.core.engine.EW_FNS` name.  ``in_shape`` is only a key
+    (the launch's jit would compile again for another input shape), so a
+    cache miss is a compile; :func:`block_launch_cache_info` counts them.
+    Under an outer jit the launch inlines."""
     n = len(plan.grid)
 
     def in_index(*gidx):
@@ -259,19 +271,24 @@ def _block_call(x: jnp.ndarray, m: MixedRadixMap, plan: BlockPlan,
         in_block[plan.src_axis[d]] = plan.block[d]
 
     in_specs = [pl.BlockSpec(tuple(in_block), in_index)]
-    args = [x]
-    if y is not None:  # epilogue operand streams in output layout
+    if ew is not None:  # epilogue operand streams in output layout
         in_specs.append(pl.BlockSpec(plan.block, lambda *g: g))
-        args.append(y)
     return pl.pallas_call(
-        _block_kernel(plan, ew),
+        _block_kernel(plan, EW_FNS[ew] if ew is not None else None),
         grid=plan.grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(plan.block, lambda *g: g),
-        out_shape=jax.ShapeDtypeStruct(m.out_shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
         name="tm_affine_block",
         interpret=interpret,
-    )(*args)
+    )
+
+
+def block_launch_cache_info():
+    """Hits and misses of the block-mode launch cache (a
+    ``functools`` cache-info tuple): a miss builds and compiles a launch,
+    a hit reuses one."""
+    return _block_launcher.cache_info()
 
 
 # ---------------------------------------------------------------------------
@@ -317,8 +334,9 @@ def tm_affine(x: jnp.ndarray, m: MixedRadixMap, *,
     plan = (None if force_mode == "gather"
             else analyze_block_mode(m, block, segment_bytes))
     if plan is not None:
-        return _block_call(x, m, plan, interpret, y=y,
-                           ew=EW_FNS[ew] if ew is not None else None)
+        launch = _block_launcher(plan, m.in_shape, m.out_shape,
+                                 jnp.dtype(x.dtype), ew, interpret)
+        return launch(x) if y is None else launch(x, y)
     sig = gather_sig(m, x.dtype, ew, segment_bytes)
     return tm_chain(sig, x, () if y is None else (y,), interpret=interpret,
                     name="tm_affine_rows")

@@ -51,6 +51,7 @@ from repro.compiler.api import CompiledTMProgram, tm_compile
 from repro.compiler.partition import partition
 from repro.core.executor import BACKENDS
 from repro.core.schedule import CycleParams
+from repro.kernels.tm_affine.tm_affine import block_launch_cache_info
 from repro.obs.hooks import HostHooks
 from repro.obs.tracer import as_tracer
 from repro.serving.batcher import (BucketQueue, Request, bucket_size,
@@ -789,6 +790,13 @@ class TMServer:
                     f"request/{r.fn_key}", "requests",
                     r.t_submit, t_end, overlap_ok=True,
                     cold=not hit, ok=True, group=prep.group)
+            # the process's block-mode launch cache, sampled per group: a
+            # miss after warm-up is a kernel compiled on the serving path
+            info = block_launch_cache_info()
+            self.tracer.counter("tmu/block_launch_hits", info.hits,
+                                track="server")
+            self.tracer.counter("tmu/block_launch_misses", info.misses,
+                                track="server")
         self._release(prep.n)
 
     def _run_phase(self, compiled: CompiledTMProgram, phase, env: dict,
