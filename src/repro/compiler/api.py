@@ -463,7 +463,10 @@ def tm_compile(fn, *example_args, params: CycleParams | None = None,
     is byte-identical with the flag in either state.
 
     ``tracer`` (a :class:`repro.obs.Tracer`) records each stage as a nested
-    span under ``compile`` with the stage's report summary attached.
+    span under ``compile`` with the stage's report summary attached, and
+    counts weight-only TM work hoisted out of the program at trace time
+    (``compile/const_hoist``; ``compile/const_hoist_shared`` for folds
+    another program over the same weights made first).
     """
     tracer = NULL_TRACER if tracer is None else tracer
     flat_in, in_tree = jax.tree_util.tree_flatten(example_args)
@@ -474,7 +477,12 @@ def tm_compile(fn, *example_args, params: CycleParams | None = None,
                     *example_args)
             out_tree = jax.tree_util.tree_structure(out_shape)
             graph = graph_from_jaxpr(closed)
-            sp.set(summary=graph.summary())
+            sp.set(summary=graph.summary(), const_hoist_bytes=sum(
+                h.nbytes for h in graph.hoisted))
+        if graph.hoisted:
+            shared = sum(h.shared for h in graph.hoisted)
+            tracer.count("compile/const_hoist", len(graph.hoisted) - shared)
+            tracer.count("compile/const_hoist_shared", shared)
         with tracer.span("compile/passes") as sp:
             pass_report = run_pipeline(graph)
             sp.set(summary=pass_report.summary())

@@ -147,6 +147,9 @@ class TMGraph:
     # opaque (traced dynamic_slice starts, matcher errors, …) — surfaced by
     # the pass report so compilations explain their TPU residue
     notes: list = dataclasses.field(default_factory=list)
+    # weight-only TM eqns the front end folded into consts above the fold
+    # limit (``trace.Hoist`` records) — surfaced by the pass report
+    hoisted: list = dataclasses.field(default_factory=list)
 
     # --- queries ----------------------------------------------------------
     def producer_index(self, name: str, before: int | None = None) -> int | None:

@@ -4,7 +4,11 @@ Each pass rewrites the :class:`~repro.compiler.ir.TMGraph` in place and
 records what it did in a :class:`PassReport` — the printed pass pipeline is
 part of the compiler's contract (tests assert which rewrites fired).
 
-Passes, in pipeline order:
+Passes, in pipeline order (after the front end's own records:
+**trace-fallback**, an eqn the matcher left opaque, and **const-hoist**, a
+weight-only TM eqn above the trace-time fold limit folded once into a
+graph const — shared across programs over the same weights — instead of
+running as a TM phase on every call):
 
 1. **compose-maps** — adjacent COARSE instructions with a single-consumer
    intermediate fuse into one instruction by exact affine map composition
@@ -66,13 +70,18 @@ class PassReport:
         return self.count("trace-fallback")
 
     @property
+    def consts_hoisted(self) -> int:
+        return self.count("const-hoist")
+
+    @property
     def phases_defragmented(self) -> int:
         return self.count("phase-defrag")
 
     def summary(self) -> str:
         lines = ["pass pipeline:"]
-        for name in ("trace-fallback", "compose-maps", "copy-elim",
-                     "epilogue-sink", "rme-legalize", "phase-defrag"):
+        for name in ("trace-fallback", "const-hoist", "compose-maps",
+                     "copy-elim", "epilogue-sink", "rme-legalize",
+                     "phase-defrag"):
             fired = [a.detail for a in self.actions if a.pass_name == name]
             lines.append(f"  {name:14s} {len(fired)} rewrite(s)")
             lines.extend(f"    - {d}" for d in fired)
@@ -343,6 +352,10 @@ def run_pipeline(graph: TMGraph) -> PassReport:
     # themselves in the same report as the rewrites
     for note in graph.notes:
         report.record("trace-fallback", note)
+    for h in graph.hoisted:
+        report.record("const-hoist",
+                      f"{h.prim} -> {h.dst} ({h.nbytes} B, "
+                      f"{'shared' if h.shared else 'folded'})")
     compose_coarse_chains(graph, report)
     eliminate_copies(graph, report)
     sink_epilogues(graph, report)

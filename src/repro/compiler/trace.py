@@ -23,10 +23,14 @@ compute into per-eqn nodes.
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import json
 import math
+import threading
+import weakref
 
+import jax
 import numpy as np
 from jax.extend.core import Literal
 
@@ -39,6 +43,15 @@ from repro.compiler.ir import Buffer, TMGraph, TMNode, TPUNode, eval_tpu_node
 # is how scalar preprocessing (e.g. jnp.pad's convert_element_type on the pad
 # value) becomes a register constant the matchers can read
 _CONST_FOLD_LIMIT = 1 << 20
+
+# weight-only TM work above that limit (the transpose of a tied embedding)
+# is hoisted: folded once per source const array and shared by every
+# program compiled over the same arrays.  Keys hold the sources' ids (jax
+# Arrays are unhashable); a weakref.finalize on each source drops its
+# entries when the weight goes.  The lock keeps two concurrent compiles
+# from folding two copies.
+_HOISTED: dict = {}  # key -> (weakrefs to the sources, folded outputs)
+_HOIST_LOCK = threading.Lock()
 
 _EW_PRIMS = {"add": EwOp.ADD, "sub": EwOp.SUB, "mul": EwOp.MUL,
              "max": EwOp.MAX}
@@ -307,6 +320,31 @@ def _match_gather(eqn, get_const, in_shapes, out_shape):
 # the walk
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass(frozen=True)
+class Hoist:
+    """One weight-only TM eqn folded into a graph const above the limit;
+    ``shared`` when another program over the same consts folded it first."""
+
+    dst: str
+    prim: str
+    nbytes: int
+    shared: bool
+
+
+def _nbytes(v) -> int:
+    return math.prod(_aval_shape(v)) * np.dtype(v.aval.dtype).itemsize
+
+
+def _hoistable(eqn) -> bool:
+    """Weight-only TM work worth holding resident: at least one const
+    array operand, and a result at most twice the bytes of the const
+    arrays it reads (no broadcast turns a weight into a larger buffer).
+    The caller has checked that every operand is a literal or a const."""
+    arrays = {v for v in eqn.invars if not isinstance(v, Literal)}
+    return bool(arrays) and (sum(_nbytes(v) for v in eqn.outvars)
+                             <= 2 * sum(_nbytes(v) for v in arrays))
+
+
 class _Builder:
     def __init__(self):
         self._n = itertools.count()
@@ -315,6 +353,7 @@ class _Builder:
         self.consts: dict = {}
         self.matched: set[str] = set()
         self.notes: list[str] = []
+        self.hoisted: list[Hoist] = []
 
     def fresh(self, prefix: str = "v") -> str:
         return f"{prefix}{next(self._n)}"
@@ -334,6 +373,35 @@ class _Builder:
         if isinstance(v, Literal):
             return self.const_buffer(v.val)
         return env[v]
+
+    def hoist(self, node: TPUNode) -> None:
+        """Fold weight-only TM work into graph consts, through the memo."""
+        eqn = node.eqn
+        srcs = [self.consts[s] for s in node.srcs]
+        key = (eqn.primitive.name, repr(sorted(eqn.params.items())),
+               tuple(None if s is None else id(self.consts[s])
+                     for s in node.src_names),
+               tuple(repr(lit) for lit in node.literals))
+        with _HOIST_LOCK:
+            hit = _HOISTED.get(key)
+            shared = hit is not None and all(
+                r() is v for r, v in zip(hit[0], srcs))
+            if shared:
+                outs = hit[1]
+            else:
+                env = {s: self.consts[s] for s in node.srcs}
+                eval_tpu_node(node, env)
+                outs = tuple(env[d] for d in node.dst_names)
+                # only immutable sources share: a numpy array folds unshared
+                if all(isinstance(v, jax.Array) for v in srcs):
+                    _HOISTED[key] = (tuple(weakref.ref(v) for v in srcs),
+                                     outs)
+                    for v in srcs:
+                        weakref.finalize(v, _HOISTED.pop, key, None)
+        for d, val, ov in zip(node.dst_names, outs, eqn.outvars):
+            self.consts[d] = val
+            self.hoisted.append(Hoist(d, eqn.primitive.name, _nbytes(ov),
+                                      shared))
 
 
 def _walk(builder: _Builder, jaxpr, consts, env) -> None:
@@ -364,10 +432,11 @@ def _walk(builder: _Builder, jaxpr, consts, env) -> None:
         # (e.g. the index-preprocessing chain inside jnp.take's jit must
         # fold so the gather matcher sees a constant index vector) — a
         # matched TM node would hide the value behind a buffer name
-        foldable = (all(isinstance(v, Literal) or env[v] in builder.consts
-                        for v in eqn.invars)
-                    and all(math.prod(_aval_shape(ov)) <= _CONST_FOLD_LIMIT
-                            for ov in eqn.outvars))
+        constant = all(isinstance(v, Literal) or env[v] in builder.consts
+                       for v in eqn.invars)
+        foldable = constant and all(
+            math.prod(_aval_shape(ov)) <= _CONST_FOLD_LIMIT
+            for ov in eqn.outvars)
 
         match = None
         if _is_matchable(eqn) and not foldable:
@@ -380,7 +449,10 @@ def _walk(builder: _Builder, jaxpr, consts, env) -> None:
                 # the whole trace; the note makes the residue explainable
                 builder.notes.append(
                     f"{name}: matcher error left opaque ({e!r})")
-        if match is not None and any(not isinstance(v, Literal)
+        # above the fold limit, TM work the matcher claims on weights alone
+        # is hoisted: it runs once here instead of as a TM phase per call
+        hoist = match is not None and constant and _hoistable(eqn)
+        if match is not None and not hoist and any(not isinstance(v, Literal)
                                      for v in eqn.invars):
             srcs = tuple(builder.operand(v, env) for v in eqn.invars
                          if not isinstance(v, Literal))
@@ -414,6 +486,9 @@ def _walk(builder: _Builder, jaxpr, consts, env) -> None:
         if foldable:  # trace-time constant folding: the value becomes a
             #           register constant downstream matchers can read
             eval_tpu_node(node, builder.consts)
+            continue
+        if hoist:
+            builder.hoist(node)
             continue
         builder.nodes.append(node)
 
@@ -455,6 +530,6 @@ def graph_from_jaxpr(closed_jaxpr) -> TMGraph:
     graph = TMGraph(nodes=builder.nodes, buffers=builder.buffers,
                     inputs=tuple(inputs), outputs=outputs,
                     consts=builder.consts, matched_prims=builder.matched,
-                    notes=builder.notes)
+                    notes=builder.notes, hoisted=builder.hoisted)
     graph.validate()
     return graph

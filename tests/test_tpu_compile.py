@@ -75,7 +75,7 @@ def _tm_affine(m, segment_bytes=None):
 # --- tm_affine block mode: pure DMA re-addressing ---------------------------
 
 @pytest.mark.parametrize("m,dtype", [
-    # decode: unembed's p["e"].T on the phi4-mini table, every step
+    # a transpose of the 200064x3072 phi4-mini embedding table
     (af.axis_permutation_map((200064, 3072), (1, 0)), jnp.bfloat16),
     # decode: GQA scores (.., S, S, group) -> (.., group, S, S)
     (af.axis_permutation_map((1, B, 8, 128, 128, 3), (0, 1, 2, 5, 3, 4)),
